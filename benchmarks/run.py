@@ -28,6 +28,8 @@ def main() -> None:
     ap.add_argument("--only", choices=SECTIONS)
     ap.add_argument("--quick", action="store_true")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sections = [args.only] if args.only else SECTIONS
     print("name,us_per_call,derived")
     failures = []

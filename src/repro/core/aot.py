@@ -136,14 +136,17 @@ def rows_fused_multitask(table_layer, task_ids, ids, dtype=jnp.float32):
 # fusion (paper §3.3: "P could be fused once training is complete")
 # ---------------------------------------------------------------------------
 
-def fuse(aot_params, cfg, opt: AoTOptions, embed: Optional[jax.Array] = None,
-         vocab_chunk: int = 8192, dtype=jnp.float32):
-    """Materialize explicit per-layer tables (L, V, d) from a reparametrization."""
-    L, V, d = cfg.num_layers, cfg.vocab_size, cfg.d_model
-    if opt.mode == "fused":
-        return {"table": aot_params["table"].astype(dtype)}
-
-    def layer_table(layer_p):
+def fused_layers(aot_params, cfg, opt: AoTOptions,
+                 embed: Optional[jax.Array] = None, vocab_chunk: int = 8192,
+                 dtype=jnp.float32):
+    """Yield each layer's explicit (V, d) table in turn (FC or Kronecker
+    reparametrization), so a caller can store one layer before the next
+    is made."""
+    L, V = cfg.num_layers, cfg.vocab_size
+    if opt.mode == "fc":
+        assert embed is not None, "FC fusion needs the embedding matrix E"
+    for i in range(L):
+        layer_p = jax.tree.map(lambda x: x[i], aot_params)
         chunks = []
         for lo in range(0, V, vocab_chunk):
             hi = min(V, lo + vocab_chunk)
@@ -153,13 +156,28 @@ def fuse(aot_params, cfg, opt: AoTOptions, embed: Optional[jax.Array] = None,
             else:
                 rows = rows_kron(layer_p, ids, opt, V, dtype)
             chunks.append(rows)
-        return jnp.concatenate(chunks, axis=0)
+        yield jnp.concatenate(chunks, axis=0)
 
-    if opt.mode == "fc":
-        assert embed is not None, "FC fusion needs the embedding matrix E"
-    tables = jnp.stack(
-        [layer_table(jax.tree.map(lambda x: x[i], aot_params)) for i in range(L)])
-    return {"table": tables}
+
+def fuse(aot_params, cfg, opt: AoTOptions, embed: Optional[jax.Array] = None,
+         vocab_chunk: int = 8192, dtype=jnp.float32):
+    """Materialize explicit per-layer tables (L, V, d) from a reparametrization."""
+    if opt.mode == "fused":
+        return {"table": aot_params["table"].astype(dtype)}
+    return {"table": jnp.stack(list(fused_layers(
+        aot_params, cfg, opt, embed, vocab_chunk, dtype)))}
+
+
+def random_fc_params(cfg, seed: int = 0, *, rank: int = 8,
+                     scale: float = 0.05):
+    """Scaled-normal FC reparametrization params (no training) and their
+    options: what :func:`random_fused` fuses into a task table."""
+    opt = AoTOptions(mode="fc", rank=rank, dropout=0.0)
+    pp = init(jax.random.PRNGKey(seed), cfg, opt)
+    pp = jax.tree.map(
+        lambda x: jax.random.normal(jax.random.PRNGKey(seed + 50),
+                                    x.shape) * scale, pp)
+    return pp, opt
 
 
 def random_fused(cfg, embed, seed: int = 0, *, rank: int = 8,
@@ -170,20 +188,47 @@ def random_fused(cfg, embed, seed: int = 0, *, rank: int = 8,
     training: FC reparametrization params overwritten with scaled normals,
     then fused the same way a trained task would be.
     """
-    opt = AoTOptions(mode="fc", rank=rank, dropout=0.0)
-    pp = init(jax.random.PRNGKey(seed), cfg, opt)
-    pp = jax.tree.map(
-        lambda x: jax.random.normal(jax.random.PRNGKey(seed + 50),
-                                    x.shape) * scale, pp)
+    pp, opt = random_fc_params(cfg, seed, rank=rank, scale=scale)
     return fuse(pp, cfg, opt, embed=embed, vocab_chunk=vocab_chunk)
 
 
-def stack_tasks(fused_list):
+def _write_block_impl(stack, block, layer, task):
+    """Write ``block`` (k, V, d) into layers ``layer..layer+k`` of task
+    ``task`` of ``stack`` (L, T, V, d). Traced offsets: one compilation
+    per block shape."""
+    return jax.lax.dynamic_update_slice(
+        stack, block[:, None].astype(stack.dtype), (layer, task, 0, 0))
+
+
+_WRITE_BLOCK = None
+
+
+def write_block(stack, block, layer: int, task: int):
+    """In-place (donated on TPU) write of one layer block of one task into
+    a stacked table: building a stack never holds two copies of it."""
+    global _WRITE_BLOCK
+    if _WRITE_BLOCK is None:
+        # CPU (tests) has no donation support, so skip it there
+        donate = (0,) if jax.default_backend() == "tpu" else ()
+        _WRITE_BLOCK = jax.jit(_write_block_impl, donate_argnums=donate)
+    return _WRITE_BLOCK(stack, block, jnp.int32(layer), jnp.int32(task))
+
+
+def stack_tasks(fused_list, dtype=None):
     """[{'table': (L, V, d)}, ...] per task -> {'table': (L, T, V, d)}.
 
     Layer-major so the model's per-layer scan slicing sees (T, V, d) slices.
+    Each task is written into a preallocated stack (``dtype``, default the
+    first table's), so beyond the caller's list the device holds the stack
+    and nothing else; tables kept on the host (a loaded checkpoint) cost
+    the device only the stack.
     """
-    return {"table": jnp.stack([f["table"] for f in fused_list], axis=1)}
+    first = fused_list[0]["table"]
+    stack = jnp.zeros((first.shape[0], len(fused_list)) + first.shape[1:],
+                      dtype or first.dtype)
+    for t, f in enumerate(fused_list):
+        stack = write_block(stack, f["table"], 0, t)
+    return {"table": stack}
 
 
 def table_bytes(cfg, n_tasks: int = 1, bytes_per_el: int = 2) -> int:

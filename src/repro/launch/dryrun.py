@@ -1,5 +1,8 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# placeholder CPU devices only: neither this process nor its --all
+# children (which inherit the environment) may claim an accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 

@@ -26,12 +26,15 @@ import argparse
 import dataclasses
 import heapq
 import sys
+from typing import Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
 from repro.core import aot as aot_mod
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import Model, ModelOptions
 from repro.obs import ServeObservability
 from repro.serve.engine import ServeConfig, ServeEngine
@@ -41,11 +44,20 @@ from repro.serve.scheduler import (PRIORITIES, ContinuousScheduler, Request,
                                    SchedulerConfig, ShedError, STANDARD)
 
 
-def demo_tasks(cfg, params, n_tasks: int):
-    """Fabricate plausibly-scaled fused tables (no training)."""
-    return [aot_mod.random_fused(cfg, params["embed"]["tok"], seed=t,
-                                 scale=0.03, vocab_chunk=4096)
-            for t in range(n_tasks)]
+def demo_tasks(cfg, params, n_tasks: int, dtype=jnp.float32):
+    """Fabricate plausibly-scaled fused tables (no training), stacked
+    {'table': (L, T, V, d)} in ``dtype``. Each layer of each task is
+    written into the stack as soon as it is made, so building holds the
+    stack plus one layer, never a list of whole tables beside it."""
+    embed = params["embed"]["tok"]
+    stack = jnp.zeros((cfg.num_layers, n_tasks, cfg.vocab_size, cfg.d_model),
+                      dtype)
+    for t in range(n_tasks):
+        pp, opt = aot_mod.random_fc_params(cfg, seed=t, scale=0.03)
+        for layer, rows in enumerate(aot_mod.fused_layers(
+                pp, cfg, opt, embed=embed, vocab_chunk=4096)):
+            stack = aot_mod.write_block(stack, rows[None], layer, t)
+    return {"table": stack}
 
 
 def load_tasks(cfg, directory: str):
@@ -67,6 +79,21 @@ def load_tasks(cfg, directory: str):
               f"({extra.get('arch', '?')})")
         tasks.append(tree)
     return tasks
+
+
+def build_engine(cfg, opts: ModelOptions, max_len: int, *,
+                 demo: int = 0, load: Optional[str] = None,
+                 seed: int = 0) -> ServeEngine:
+    """Model, seeded params (in ``opts.param_dtype``), the stacked fused
+    tables — ``demo`` fabricated tasks, or the ones exported under
+    ``load`` — and the engine that serves them. The engine holds the only
+    device copy of the tables: nothing here outlives the call."""
+    model = Model(cfg, opts)
+    params = model.init(jax.random.PRNGKey(seed))
+    tables = (aot_mod.stack_tasks(load_tasks(cfg, load), opts.param_dtype)
+              if load else demo_tasks(cfg, params, demo, opts.param_dtype))
+    return ServeEngine(model, params, ServeConfig(max_len=max_len),
+                       fused_tasks=tables)
 
 
 def parse_priority_mix(spec: str):
@@ -343,6 +370,7 @@ def main():
                        help="TTFT SLO target in real scheduler ticks "
                             "(attainment reported with --metrics)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if not args.demo and not args.load:
         ap.error("pass --demo (fabricated tables) or --load DIR "
@@ -368,17 +396,13 @@ def main():
     cfg = configs.get(args.arch)
     if args.reduced:
         cfg = configs.reduced(cfg, repeats=2)
-    model = Model(cfg, ModelOptions(chunk_q=64, chunk_kv=args.max_len))
-    params = model.init(jax.random.PRNGKey(0))
-
-    tasks = (demo_tasks(cfg, params, args.tasks) if args.demo
-             else load_tasks(cfg, args.load))
-    n_tasks = len(tasks)
+    eng = build_engine(cfg, ModelOptions(chunk_q=64, chunk_kv=args.max_len),
+                       args.max_len, demo=args.tasks if args.demo else 0,
+                       load=args.load)
+    n_tasks = eng.num_tasks
     print(f"serving {n_tasks} tasks; fused tables "
           f"{aot_mod.table_bytes(cfg, n_tasks, 2) / 1e6:.1f} MB total")
 
-    eng = ServeEngine(model, params, ServeConfig(max_len=args.max_len),
-                      fused_tasks=tasks)
     rng = np.random.default_rng(0)
 
     if args.static:

@@ -24,6 +24,7 @@ from repro.core import peft as peft_mod
 from repro.data.pipeline import LMStream
 from repro.distrib import axes as axlib
 from repro.distrib import sharding as shlib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models.model import Model, ModelOptions
 from repro.train.loop import TrainLoop
@@ -48,6 +49,7 @@ def main():
                     help="e.g. 2x4 => (data=2, model=4); empty = no mesh")
     ap.add_argument("--ckpt-dir", default="results/launch_train")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = configs.get(args.arch)
     if args.reduced:

@@ -147,7 +147,9 @@ class Model:
         params["final_norm"] = L.norm_init(cfg)
         if not cfg.tie_embeddings:
             params["lm_head"] = {"w": L.dense_init(keys[3], (cfg.d_model, cfg.vocab_size))}
-        return params
+        # drawn in float32, then stored as opts.param_dtype: the same seed
+        # gives the same weights, rounded, in every dtype
+        return jax.tree.map(lambda x: x.astype(self.opts.param_dtype), params)
 
     def param_count(self, params) -> int:
         return sum(x.size for x in jax.tree.leaves(params))

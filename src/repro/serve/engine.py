@@ -61,25 +61,32 @@ class DispatchFault(RuntimeError):
 
 class ServeEngine:
     def __init__(self, model: Model, params, cfg: ServeConfig = ServeConfig(),
-                 fused_tasks: Optional[list] = None, peft=None):
-        """``fused_tasks``: list of {'table': (L, V, d)} — one per task.
-        ``peft``: alternatively a ready peft bundle (baseline methods)."""
+                 fused_tasks=None, peft=None):
+        """``fused_tasks``: a list of {'table': (L, V, d)}, one per task, or
+        those tables already stacked as {'table': (L, T, V, d)}
+        (:func:`repro.core.aot.stack_tasks`) — the engine then holds the
+        only device copy. ``peft``: alternatively a ready peft bundle
+        (baseline methods). The PEFT parameters enter every jitted step as
+        arguments, never as closed-over constants, so a multi-GB table is
+        neither baked into the programs nor copied beside itself."""
         self.model = model
         self.params = params
         self.cfg = cfg
         if fused_tasks is not None:
-            stacked = aot_mod.stack_tasks(fused_tasks)
+            stacked = (fused_tasks if isinstance(fused_tasks, dict)
+                       else aot_mod.stack_tasks(fused_tasks))
             opt = peft_mod.PEFTOptions(
                 method="aot", aot=aot_mod.AoTOptions(mode="fused"))
             self.peft = peft_mod.make({"aot": stacked}, opt)
             self.multitask = True
             # task-id validity bound: the scheduler rejects submissions
             # whose task_id a fused-table gather would silently clamp/wrap
-            self.num_tasks: Optional[int] = len(fused_tasks)
+            self.num_tasks: Optional[int] = stacked["table"].shape[1]
         else:
             self.peft = peft
             self.multitask = False
             self.num_tasks = None
+        self._pp = self.peft["params"] if self.peft is not None else None
         # KV allocations round up so the Pallas decode kernel never hits its
         # pad-and-copy fallback (S % block_k != 0); rows past cfg.max_len
         # stay masked by cur_len forever.
@@ -135,47 +142,50 @@ class ServeEngine:
         self._pending_fault = (kind, slot)
 
     # ------------------------------------------------------------------
-    def _peft_for(self, task_ids):
-        if not self.multitask:
-            return self.peft
-        p = dict(self.peft)
-        p["task_ids"] = task_ids
+    def _peft_for(self, pp, task_ids):
+        """Rebuild the peft bundle around the traced parameters ``pp``."""
+        if self.peft is None:
+            return None
+        p = peft_mod.make(pp, self.peft["opt"])
+        if self.multitask:
+            p["task_ids"] = task_ids
         return p
 
-    def _prefill_impl(self, params, tokens, task_ids, extra=None):
+    def _prefill_impl(self, params, pp, tokens, task_ids, extra=None):
         batch = {"tokens": tokens}
         if extra:
             batch.update(extra)
-        peft = self._peft_for(task_ids)
+        peft = self._peft_for(pp, task_ids)
         return self.model.prefill(params, batch, peft, max_len=self.cache_len)
 
-    def _prefill_at_impl(self, params, tokens, last_pos, task_ids):
+    def _prefill_at_impl(self, params, pp, tokens, last_pos, task_ids):
         """Bucket prefill: logits taken at ``last_pos`` (last real token)."""
-        peft = self._peft_for(task_ids)
+        peft = self._peft_for(pp, task_ids)
         return self.model.prefill(params, {"tokens": tokens}, peft,
                                   max_len=self.cache_len, last_pos=last_pos)
 
-    def _decode_impl(self, params, tokens, pos, cache, task_ids):
-        peft = self._peft_for(task_ids)
+    def _decode_impl(self, params, pp, tokens, pos, cache, task_ids):
+        peft = self._peft_for(pp, task_ids)
         return self.model.decode_step(params, tokens, pos, cache, peft)
 
     # sampled variant: the decode step and the per-slot token draw fuse
     # into one jitted pass (temperature 0 rows reduce to exact argmax)
-    def _decode_sampled_impl(self, params, tokens, pos, cache, task_ids,
+    def _decode_sampled_impl(self, params, pp, tokens, pos, cache, task_ids,
                              temps, top_ks, top_ps, base_keys, steps):
-        logits, cache = self._decode_impl(params, tokens, pos, cache, task_ids)
+        logits, cache = self._decode_impl(params, pp, tokens, pos, cache,
+                                          task_ids)
         toks = sample_tokens(logits[:, -1], temps, top_ks, top_ps,
                              base_keys, steps)
         return toks, cache
 
-    def _serve_step_impl(self, params, tokens, token_rows, token_pos,
+    def _serve_step_impl(self, params, pp, tokens, token_rows, token_pos,
                          logit_idx, cache, token_tasks, block_tables, temps,
                          top_ks, top_ps, base_keys, steps, *, stochastic):
         """The whole paged tick in one jit: unified ragged model step over
         the packed token list + per-slot token draw. Greedy batches trace
         with ``stochastic=False`` (pure argmax, the bitwise-parity fast
         path); the masking/draw work only exists in the stochastic trace."""
-        peft = self._peft_for(token_tasks)
+        peft = self._peft_for(pp, token_tasks)
         logits, cache = self.model.mixed_step(
             params, tokens, token_rows, token_pos, cache, peft,
             block_tables=block_tables, logit_idx=logit_idx)
@@ -185,6 +195,25 @@ class ServeEngine:
         else:
             toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return toks, logits, cache
+
+    def serve_step_shapes(self, cache, num_slots: int, npages: int,
+                          width: int):
+        """Abstract arguments of the jitted serve step (``_serve_greedy`` /
+        ``_serve_sampled``) for a tick of packed width ``width`` over a
+        paged pool ``cache`` of ``num_slots`` block tables of ``npages``:
+        lowering with them compiles the program such a tick runs."""
+        def sds(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype)
+        shape_of = lambda x: sds(x.shape, x.dtype)      # noqa: E731
+        i32, f32 = jnp.int32, jnp.float32
+        return (jax.tree.map(shape_of, self.params),
+                jax.tree.map(shape_of, self._pp),
+                sds((width, 1), i32), sds((width,), i32), sds((width,), i32),
+                sds((num_slots,), i32), jax.tree.map(shape_of, cache),
+                sds((width,), i32), sds((num_slots, npages), i32),
+                sds((num_slots,), f32), sds((num_slots,), i32),
+                sds((num_slots,), f32), sds((num_slots, 2), jnp.uint32),
+                sds((num_slots,), i32))
 
     def _sample_row_impl(self, logits_row, temps, top_ks, top_ps, base_keys,
                          steps):
@@ -203,12 +232,14 @@ class ServeEngine:
         b, s = prompts.shape
         tids = jnp.asarray(task_ids if task_ids is not None
                            else np.zeros(b, np.int32))
-        logits, cache, pos = self._prefill(self.params, jnp.asarray(prompts), tids)
+        logits, cache, pos = self._prefill(self.params, self._pp,
+                                          jnp.asarray(prompts), tids)
         out = []
         tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
         for i in range(steps):
             out.append(tok)
-            logits, cache = self._decode(self.params, tok, pos + i, cache, tids)
+            logits, cache = self._decode(self.params, self._pp, tok, pos + i,
+                                         cache, tids)
             tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
         return np.asarray(jnp.concatenate(out, axis=1))
 
@@ -239,8 +270,8 @@ class ServeEngine:
         ``[0, length)`` match an unpadded prefill bitwise."""
         tids = jnp.full((1,), task_id, jnp.int32)
         logits, cache, _ = self._prefill_at(
-            self.params, jnp.asarray(tokens), jnp.asarray(length - 1, jnp.int32),
-            tids)
+            self.params, self._pp, jnp.asarray(tokens),
+            jnp.asarray(length - 1, jnp.int32), tids)
         self._count("prefill")
         return self._first_tokens(logits, sample), cache
 
@@ -270,14 +301,16 @@ class ServeEngine:
         self._count("decode_mixed")
         if sample is None:
             logits, cache = self._decode(
-                self.params, jnp.asarray(tokens), jnp.asarray(pos, np.int32),
-                cache, jnp.asarray(task_ids, np.int32))
+                self.params, self._pp, jnp.asarray(tokens),
+                jnp.asarray(pos, np.int32), cache,
+                jnp.asarray(task_ids, np.int32))
             toks = np.asarray(jax.device_get(
                 jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)))
             return toks, cache
         toks, cache = self._decode_sampled(
-            self.params, jnp.asarray(tokens), jnp.asarray(pos, np.int32),
-            cache, jnp.asarray(task_ids, np.int32), *self._sample_vecs(sample))
+            self.params, self._pp, jnp.asarray(tokens),
+            jnp.asarray(pos, np.int32), cache, jnp.asarray(task_ids, np.int32),
+            *self._sample_vecs(sample))
         return np.asarray(jax.device_get(toks)), cache
 
     def serve_step(self, tokens: np.ndarray, token_rows: np.ndarray,
@@ -310,7 +343,8 @@ class ServeEngine:
         temps = np.asarray(sample[0])
         fn = self._serve_sampled if np.any(temps > 0.0) else self._serve_greedy
         toks, logits, cache = fn(
-            self.params, jnp.asarray(tokens), jnp.asarray(token_rows, np.int32),
+            self.params, self._pp, jnp.asarray(tokens),
+            jnp.asarray(token_rows, np.int32),
             jnp.asarray(token_pos, np.int32), jnp.asarray(logit_idx, np.int32),
             cache, jnp.asarray(token_tasks, np.int32),
             jnp.asarray(block_tables, np.int32), *self._sample_vecs(sample))

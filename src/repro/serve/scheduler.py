@@ -72,7 +72,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.obs import NULL_OBS, ServeObservability
-from repro.serve.engine import ServeEngine
+from repro.serve.engine import DispatchFault, ServeEngine
 from repro.serve.kv_pool import PagedKVPool, SlotKVPool
 from repro.serve.recovery import NULL_JOURNAL, RequestJournal
 from repro.serve.sampling import SamplingParams, request_base_key
@@ -1368,10 +1368,13 @@ class ContinuousScheduler:
             return
         ns, qw = self.cfg.num_slots, self._qw
         # ---- the self-healing dispatch loop --------------------------
-        # Pack + dispatch run inside a retry loop. A dispatch that RAISES
-        # (device fault, injected alloc failure) mutated no host state —
-        # pool.cache is only replaced on success — so the tick simply
-        # repacks and retries, up to cfg.tick_retries, then re-raises.
+        # Pack + dispatch run inside a retry loop. A dispatch that raises
+        # DispatchFault (the transient fault this loop exists for) mutated
+        # no host state — pool.cache is only replaced on success — so the
+        # tick simply repacks and retries, up to cfg.tick_retries, then
+        # re-raises. Any other exception (a compile error, device memory
+        # exhausted) would fail the same way again, after recompiling the
+        # step: it propagates on the first attempt.
         # A dispatch that returns NON-FINITE logits for a live row (the
         # watchdog check: real NaN/inf or an injected poison) quarantines
         # that row's whole request group and retries with the survivors —
@@ -1427,7 +1430,7 @@ class ContinuousScheduler:
                         tokens, token_rows, token_pos, logit_idx,
                         self.pool.cache, self.pool.block_tables,
                         self.pool.task_id[token_rows], sample)
-            except Exception as e:
+            except DispatchFault as e:
                 self.dispatch_faults += 1
                 self._m_dispatch_faults.inc()
                 tr.instant("dispatch_fault", error=type(e).__name__)

@@ -16,7 +16,9 @@ The contracts under test:
     (terminal QUARANTINED state, pages held for forensics) and retries
     the tick with the survivors, whose streams are bitwise unchanged;
   * a faulted dispatch (``DispatchFault``) is retried up to
-    ``tick_retries`` times, then re-raised;
+    ``tick_retries`` times, then re-raised; any other exception from the
+    dispatch (a compile error, device memory exhausted) propagates on the
+    first attempt, unretried;
   * ``PagedKVPool.compact()`` deduplicates identical prompt pages as an
     admission rescue before preempt-and-recompute kicks in;
   * malformed SchedulerConfig knobs and ``shutdown(grace_ticks)`` bounce
@@ -368,6 +370,50 @@ def test_dispatch_fault_exhausts_retries(mt_engine, rng, monkeypatch):
     with pytest.raises(DispatchFault):
         sched.step()
     assert len(calls) == 2               # first attempt + tick_retries
+
+
+@pytest.mark.parametrize("error", [RuntimeError, MemoryError, ValueError])
+def test_non_dispatch_fault_propagates_unretried(mt_engine, rng, monkeypatch,
+                                                 error):
+    """Only DispatchFault is transient: a compile error or exhausted device
+    memory would fail the same way again after recompiling the step, so it
+    propagates on the first attempt and no retry is counted."""
+    cfg, eng = mt_engine
+    sched = _sched(eng, tick_retries=2)
+    sched.submit(_req(cfg, rng, 0, plen=8, max_new=4))
+    calls = []
+
+    def boom(*a, **kw):
+        calls.append(1)
+        raise error("RESOURCE_EXHAUSTED: out of memory in hbm")
+
+    monkeypatch.setattr(eng, "serve_step", boom)
+    with pytest.raises(error):
+        sched.step()
+    assert len(calls) == 1
+    assert sched.dispatch_faults == 0 and sched.tick_retries_used == 0
+
+
+def test_compile_cache_dir(monkeypatch):
+    """The entry points' cache helper leaves JAX_COMPILATION_CACHE_DIR to
+    JAX where it is set, and otherwise sets one fixed directory inside the
+    checkout — the same on every call, so a later process finds it."""
+    import jax
+    from pathlib import Path
+    from repro.launch import compile_cache
+    checkout = Path(__file__).resolve().parents[1]
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(compile_cache.ENV, "/elsewhere/jax-cache")
+        assert compile_cache.enable_compile_cache() == "/elsewhere/jax-cache"
+        assert jax.config.jax_compilation_cache_dir == was   # nothing set
+        monkeypatch.delenv(compile_cache.ENV)
+        first = compile_cache.enable_compile_cache()
+        assert first == compile_cache.enable_compile_cache()
+        assert Path(first) == checkout / ".jax_cache"
+        assert jax.config.jax_compilation_cache_dir == first
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
 
 
 # ---------------------------------------------------------------------------
