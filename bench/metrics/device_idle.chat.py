@@ -1,0 +1,12 @@
+"""Share of the traced slice in which no operation ran on the device:
+1 - (union of device-op intervals) / (slice length)."""
+from bench import readings
+
+LAYER = "device"
+UNIT = "%"
+SOURCE = "device_trace"
+MOVES = "itl_p95_ms"
+
+
+def read(r):
+    return readings.device_idle(r)
