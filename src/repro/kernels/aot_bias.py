@@ -55,7 +55,8 @@ def _kernel_mt(sc_ref, h_ref, p_ref, o_ref, *, block_t, group):
     _add_row(h_ref, p_ref[...], sc_ref[1, tok] % group, o_ref)
 
 
-def _call(kernel, scalars, h, table, table_index, *, block_t, interpret):
+def _call(kernel, scalars, h, table, table_index, *, block_t, interpret,
+          name):
     T, d = h.shape
     group = min(GROUP, table.shape[-2])
     pad = (-T) % block_t
@@ -77,6 +78,7 @@ def _call(kernel, scalars, h, table, table_index, *, block_t, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T + pad, d), h.dtype),
         interpret=interpret,
+        name=name,
     )(sp, hp, table)
     return out[:T] if pad else out
 
@@ -90,7 +92,7 @@ def aot_gather_add_kernel(h, table, ids, *, block_t: int = 16,
     """
     return _call(_kernel, ids.astype(jnp.int32), h, table,
                  lambda s, tok, group: (s[tok] // group, 0),
-                 block_t=block_t, interpret=interpret)
+                 block_t=block_t, interpret=interpret, name="aot_gather_add")
 
 
 def aot_gather_add_multitask_kernel(h, tables, task_ids, ids, *,
@@ -103,4 +105,5 @@ def aot_gather_add_multitask_kernel(h, tables, task_ids, ids, *,
     sc = jnp.stack([task_ids.astype(jnp.int32), ids.astype(jnp.int32)], axis=0)
     return _call(_kernel_mt, sc, h, tables,
                  lambda s, tok, group: (s[0, tok], s[1, tok] // group, 0),
-                 block_t=block_t, interpret=interpret)
+                 block_t=block_t, interpret=interpret,
+                 name="aot_gather_add_multitask")

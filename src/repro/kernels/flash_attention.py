@@ -117,6 +117,7 @@ def flash_attention_kernel(q, k, v, *, causal=True, window=0, sm_scale=None,
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qf, kf, vf)
     out = out.reshape(b, h, sqp, hd).transpose(0, 2, 1, 3)
     return out[:, :sq]

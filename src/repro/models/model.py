@@ -435,32 +435,37 @@ class Model:
 
         # --- the paper's mechanism: input-dependent bias BEFORE the layer ---
         if method == "aot":
-            bias = self._aot_bias(peft, peft_u, ids, e_rows, rng_layer)
-            if bias is not None:
-                h = h + bias.astype(dt)
+            with jax.named_scope("aot_bias"):
+                bias = self._aot_bias(peft, peft_u, ids, e_rows, rng_layer)
+                if bias is not None:
+                    h = h + bias.astype(dt)
 
         new_cache = cache_u
         if kind == BLOCK_ATTN:
             from jax.ad_checkpoint import checkpoint_name
             if cfg.post_ln:
-                att, new_cache = self._attention(bp, h, positions, peft, peft_u,
-                                                 cache_u, decode_pos, prompt_len,
-                                                 block_tables, token_rows)
+                with jax.named_scope("attention"):
+                    att, new_cache = self._attention(
+                        bp, h, positions, peft, peft_u, cache_u, decode_pos,
+                        prompt_len, block_tables, token_rows)
                 h = L.apply_norm(cfg, bp["ln1"], h + att)
-                ffn, aux = self._ffn(bp, h, peft, peft_u, moe_flag)
+                with jax.named_scope("mlp"):
+                    ffn, aux = self._ffn(bp, h, peft, peft_u, moe_flag)
                 h = L.apply_norm(cfg, bp["ln2"], h + ffn)
             else:
-                att, new_cache = self._attention(bp, L.apply_norm(cfg, bp["ln1"], h),
-                                                 positions, peft, peft_u,
-                                                 cache_u, decode_pos, prompt_len,
-                                                 block_tables, token_rows)
+                with jax.named_scope("attention"):
+                    att, new_cache = self._attention(
+                        bp, L.apply_norm(cfg, bp["ln1"], h), positions, peft,
+                        peft_u, cache_u, decode_pos, prompt_len, block_tables,
+                        token_rows)
                 # SP-sharded, (b, s/TP, d)-sized: cheap to save so the remat
                 # policy can skip recomputing attention in the backward pass
                 att = checkpoint_name(att, "attn_mix")
                 h = h + att
                 if "mlp" in bp or moe_flag:
-                    ffn, aux = self._ffn(bp, L.apply_norm(cfg, bp["ln2"], h),
-                                         peft, peft_u, moe_flag)
+                    with jax.named_scope("mlp"):
+                        ffn, aux = self._ffn(bp, L.apply_norm(cfg, bp["ln2"], h),
+                                             peft, peft_u, moe_flag)
                     h = h + ffn
         elif kind == BLOCK_RGLRU:
             mix, new_cache = rec_mod.apply_rglru(cfg, bp["rglru"],
@@ -831,11 +836,13 @@ class Model:
                 decode_pos=token_pos, prompt_len=0,
                 block_tables=block_tables, token_rows=token_rows)
             new_cache.append(_xs_to_unitdict(gc))
-        h = L.apply_norm(cfg, params["final_norm"], h)
-        if logit_idx is None:
-            logit_idx = jnp.arange(h.shape[0], dtype=jnp.int32)
-        h_sel = jnp.take(h[:, 0], logit_idx, axis=0)            # (slots, d)
-        return self.unembed(params, h_sel[:, None])[:, 0], new_cache
+        with jax.named_scope("logits"):
+            h = L.apply_norm(cfg, params["final_norm"], h)
+            if logit_idx is None:
+                logit_idx = jnp.arange(h.shape[0], dtype=jnp.int32)
+            h_sel = jnp.take(h[:, 0], logit_idx, axis=0)        # (slots, d)
+            logits = self.unembed(params, h_sel[:, None])[:, 0]
+        return logits, new_cache
 
 
 # ---------------------------------------------------------------------------

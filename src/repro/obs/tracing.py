@@ -14,12 +14,13 @@ The tracer is deliberately host-only and allocation-light: a disabled
 tracer's :meth:`span` returns one shared reusable null context and its
 event methods are no-ops, so tracing can stay compiled into the
 scheduler's hot loop. Like the metrics registry it never reaches inside
-jitted code — device-side detail comes from the optional
-``jax.profiler`` bracket (:meth:`start` / :meth:`stop`), which writes a
-separate XLA trace whose wall clock lines up with these scheduler spans
-(each span is additionally annotated via ``jax.profiler.TraceAnnotation``
-while the bracket is open, so device events nest under the owning tick
-in the profiler UI).
+jitted code. An enabled tracer also opens every span as a
+``jax.profiler.TraceAnnotation`` carrying the span's arguments, so any
+profiler session that is collecting (the opt-in bracket of :meth:`start`
+/ :meth:`stop`, ``jax.profiler.trace``, a profiler server) records the
+span, with its arguments as event stats, on the host plane of the same
+clock as the device's programs; with no session collecting the
+annotation costs about a microsecond.
 """
 from __future__ import annotations
 
@@ -27,6 +28,8 @@ import json
 import time
 from contextlib import contextmanager
 from typing import List, Optional
+
+from jax.profiler import TraceAnnotation, start_trace, stop_trace
 
 
 class _NullContext:
@@ -73,21 +76,16 @@ class TickTracer:
 
     @contextmanager
     def _span(self, name: str, args: dict):
-        if self._profiling:
-            import jax
-            ann = jax.profiler.TraceAnnotation(name)
-            ann.__enter__()
-        t0 = self._now_us()
-        try:
-            yield None
-        finally:
-            ev = {"ph": "X", "pid": 0, "tid": 0, "name": name,
-                  "ts": t0, "dur": self._now_us() - t0}
-            if args:
-                ev["args"] = args
-            self.events.append(ev)
-            if self._profiling:
-                ann.__exit__(None, None, None)
+        with TraceAnnotation(name, **args):
+            t0 = self._now_us()
+            try:
+                yield None
+            finally:
+                ev = {"ph": "X", "pid": 0, "tid": 0, "name": name,
+                      "ts": t0, "dur": self._now_us() - t0}
+                if args:
+                    ev["args"] = args
+                self.events.append(ev)
 
     def instant(self, name: str, **args) -> None:
         """Zero-duration lifecycle marker (finish / preempt / fork)."""
@@ -114,14 +112,12 @@ class TickTracer:
         """Open the opt-in device-profiler bracket (no-op without a
         ``jax_profile_dir``)."""
         if self.enabled and self.jax_profile_dir and not self._profiling:
-            import jax
-            jax.profiler.start_trace(self.jax_profile_dir)
+            start_trace(self.jax_profile_dir)
             self._profiling = True
 
     def stop(self) -> None:
         if self._profiling:
-            import jax
-            jax.profiler.stop_trace()
+            stop_trace()
             self._profiling = False
 
     # ------------------------------------------------------------------

@@ -29,7 +29,6 @@ bitfit, and plain backbone.
 """
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass
 from typing import Any, Optional, Tuple
 
@@ -41,6 +40,7 @@ from repro.core import aot as aot_mod
 from repro.core import peft as peft_mod
 from repro.kernels.decode_attention import round_kv_len
 from repro.models.model import Model
+from repro.obs.tracing import NULL_TRACER
 from repro.serve.sampling import sample_tokens
 
 
@@ -97,32 +97,49 @@ class ServeEngine:
         self._decode_sampled = jax.jit(self._decode_sampled_impl)
         self._sample_row = jax.jit(self._sample_row_impl)
         # the unified ragged prefill+decode step: two traces (greedy batches
-        # keep the exact-argmax path), each still ONE dispatch per tick
-        self._serve_greedy = jax.jit(
-            functools.partial(self._serve_step_impl, stochastic=False))
-        self._serve_sampled = jax.jit(
-            functools.partial(self._serve_step_impl, stochastic=True))
+        # keep the exact-argmax path), each still ONE dispatch per tick.
+        # Named functions, so the device trace names the programs
+        # jit_serve_step_greedy / jit_serve_step_sampled
+
+        def serve_step_greedy(*args):
+            return self._serve_step_impl(*args, stochastic=False)
+
+        def serve_step_sampled(*args):
+            return self._serve_step_impl(*args, stochastic=True)
+        self._serve_greedy = jax.jit(serve_step_greedy)
+        self._serve_sampled = jax.jit(serve_step_sampled)
         # host-visible device-dispatch counter (serve-path calls only):
         # the scheduler asserts one dispatch per unified tick and the
         # launcher reports dispatches/tick
         self.dispatches = 0
+        # the watchdog's finite_rows program, launched after every
+        # serve_step: a tick runs two programs until finiteness comes
+        # back from serve_step itself
+        self.finite_rows_dispatches = 0
         self._m = None                  # optional obs per-kind counters
-        # one-shot injected dispatch fault (see inject_fault) + the tiny
-        # jitted per-slot finiteness check the watchdog reads every tick
+        self.tracer = NULL_TRACER       # the scheduler's, once attached
+        # one-shot injected dispatch fault (see inject_fault)
         self._pending_fault: Optional[Tuple[str, int]] = None
-        self._finite_rows = jax.jit(
-            lambda l: jnp.all(jnp.isfinite(l), axis=-1))
 
     def attach_metrics(self, registry) -> None:
         """Per-kind dispatch counters on an obs registry. Incremented on
         the host around the jitted calls, never inside them — a tick's
         dispatch anatomy (serve_step vs legacy prefill+decode pairs vs
-        n>1 first-token draws) becomes visible without touching traces."""
+        n>1 first-token draws, and the watchdog's finite_rows) becomes
+        visible without touching traces."""
         self._m = {kind: registry.counter(
             f"engine_dispatch_{kind}_total",
             f"device dispatches via {kind}")
             for kind in ("serve_step", "prefill", "decode_mixed",
-                         "sample_first")}
+                         "sample_first", "finite_rows")}
+
+    def attach_tracer(self, tracer) -> None:
+        """Record the engine's host phases (``engine.inputs``,
+        ``engine.launch``, ``engine.outputs``) on ``tracer``, nested in
+        the scheduler's ``dispatch`` span. The scheduler attaches its own
+        tracer, the null one included, so an engine reused under an
+        untraced scheduler records nothing."""
+        self.tracer = tracer
 
     def _count(self, kind: str) -> None:
         self.dispatches += 1
@@ -189,11 +206,12 @@ class ServeEngine:
         logits, cache = self.model.mixed_step(
             params, tokens, token_rows, token_pos, cache, peft,
             block_tables=block_tables, logit_idx=logit_idx)
-        if stochastic:
-            toks = sample_tokens(logits, temps, top_ks, top_ps, base_keys,
-                                 steps)
-        else:
-            toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("sampling"):
+            if stochastic:
+                toks = sample_tokens(logits, temps, top_ks, top_ps,
+                                     base_keys, steps)
+            else:
+                toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return toks, logits, cache
 
     def serve_step_shapes(self, cache, num_slots: int, npages: int,
@@ -284,9 +302,14 @@ class ServeEngine:
         """Draw the spec's first tokens from ONE logits row — the n>1
         parallel-samples path, where every sample's token 0 comes from the
         same prefill row under its own stream."""
-        toks = self._sample_row(logits_row, *self._sample_vecs(sample))
-        self._count("sample_first")
-        return [int(t) for t in np.asarray(jax.device_get(toks))]
+        tr = self.tracer
+        with tr.span("engine.inputs"):
+            vecs = self._sample_vecs(sample)
+        with tr.span("engine.launch"):
+            toks = self._sample_row(logits_row, *vecs)
+            self._count("sample_first")
+        with tr.span("engine.outputs"):
+            return [int(t) for t in np.asarray(jax.device_get(toks))]
 
     def decode_mixed(self, tokens: np.ndarray, pos: np.ndarray, cache,
                      task_ids: np.ndarray, sample=None):
@@ -335,21 +358,39 @@ class ServeEngine:
         (num_slots, V) still on device, new pool cache, per-slot finite
         flags (num_slots,) bool np — the watchdog input: False means that
         slot's reported logits row contains NaN/inf and its token must not
-        be trusted)."""
-        fault, self._pending_fault = self._pending_fault, None
-        if fault is not None and fault[0] == "alloc_failure":
-            raise DispatchFault(
-                "injected allocation failure before dispatch (fault plan)")
-        temps = np.asarray(sample[0])
-        fn = self._serve_sampled if np.any(temps > 0.0) else self._serve_greedy
-        toks, logits, cache = fn(
-            self.params, self._pp, jnp.asarray(tokens),
-            jnp.asarray(token_rows, np.int32),
-            jnp.asarray(token_pos, np.int32), jnp.asarray(logit_idx, np.int32),
-            cache, jnp.asarray(token_tasks, np.int32),
-            jnp.asarray(block_tables, np.int32), *self._sample_vecs(sample))
-        if fault is not None:           # kind == "nan": poison post-jit,
-            logits = logits.at[fault[1]].set(jnp.nan)   # pre-watchdog
-        self._count("serve_step")
-        finite = np.asarray(jax.device_get(self._finite_rows(logits)))
-        return np.asarray(jax.device_get(toks)), logits, cache, finite
+        be trusted; a second program, :func:`finite_rows`, computes them).
+        The host work opens ``engine.inputs``, ``engine.launch`` and
+        ``engine.outputs`` spans on the attached tracer."""
+        tr = self.tracer
+        with tr.span("engine.inputs"):
+            fault, self._pending_fault = self._pending_fault, None
+            if fault is not None and fault[0] == "alloc_failure":
+                raise DispatchFault(
+                    "injected allocation failure before dispatch (fault plan)")
+            temps = np.asarray(sample[0])
+            fn = (self._serve_sampled if np.any(temps > 0.0)
+                  else self._serve_greedy)
+            args = (jnp.asarray(tokens), jnp.asarray(token_rows, np.int32),
+                    jnp.asarray(token_pos, np.int32),
+                    jnp.asarray(logit_idx, np.int32), cache,
+                    jnp.asarray(token_tasks, np.int32),
+                    jnp.asarray(block_tables, np.int32),
+                    *self._sample_vecs(sample))
+        with tr.span("engine.launch"):
+            toks, logits, cache = fn(self.params, self._pp, *args)
+            if fault is not None:       # kind == "nan": poison post-jit,
+                logits = logits.at[fault[1]].set(jnp.nan)   # pre-watchdog
+            self._count("serve_step")
+        with tr.span("engine.outputs"):
+            finite = np.asarray(jax.device_get(finite_rows(logits)))
+            self.finite_rows_dispatches += 1
+            if self._m is not None:
+                self._m["finite_rows"].inc()
+            return np.asarray(jax.device_get(toks)), logits, cache, finite
+
+
+@jax.jit
+def finite_rows(logits):
+    """The watchdog's per-slot check: False where a slot's logits row
+    holds NaN or inf."""
+    return jnp.all(jnp.isfinite(logits), axis=-1)

@@ -431,6 +431,7 @@ class ContinuousScheduler:
         if self.obs.metrics.enabled:
             self.pool.attach_metrics(self.obs.metrics)
             engine.attach_metrics(self.obs.metrics)
+        engine.attach_tracer(self.obs.tracer)
         m = self.obs.metrics
         self._m_ticks = m.counter(
             "sched_ticks_total", "real step() calls (no idle fast-forward)")

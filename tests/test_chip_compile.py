@@ -12,6 +12,7 @@ one process may load the TPU library, and each test worker imports every
 test file. All such compiles stay in this one file for that reason.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -80,6 +81,22 @@ def test_ragged_kernel_compiles(one_chip, width):
     _compile(ragged_paged_attention_kernel, one_chip,
              ((width, H, HD), bf16), page, page, ((SLOTS, NPAGES), i32),
              ((width,), i32), ((width,), i32))
+
+
+def test_ragged_kernel_keeps_its_trace_name(one_chip):
+    """A device trace names each op by its HLO instruction: the ragged
+    kernel's stays ``%ragged_paged_attention.<n>``, the name the chip
+    benchmark's kernel readings match."""
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    page = ((NUM_BLOCKS, BLOCK, KVH, HD), bf16)
+    compiled = _compile(ragged_paged_attention_kernel, one_chip,
+                        ((SLOTS, H, HD), bf16), page, page,
+                        ((SLOTS, NPAGES), i32), ((SLOTS,), i32),
+                        ((SLOTS,), i32))
+    calls = [line.strip() for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls and all(re.match(r"%ragged_paged_attention\.\d+ = ", c)
+                         for c in calls), calls
 
 
 def test_flash_kernel_compiles(one_chip):

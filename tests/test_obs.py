@@ -263,7 +263,8 @@ def test_observability_does_not_change_tokens(rng, obs_engine, tmp_path,
                                               sampled):
     """Identical request streams with obs fully on vs off must produce
     bitwise-identical token streams — metrics read host scalars between
-    device steps and never enter jitted code."""
+    device steps and never enter jitted code, and the engine's spans sit
+    around its jitted calls, never inside them."""
     cfg, eng = obs_engine
     seed = int(rng.integers(0, 2**31))
     r1 = np.random.default_rng(seed)
@@ -277,11 +278,13 @@ def test_observability_does_not_change_tokens(rng, obs_engine, tmp_path,
             np.asarray(fin_on[rid].out), np.asarray(fin_off[rid].out),
             err_msg=f"req {rid}: observability changed the tokens "
                     f"({'stochastic' if sampled else 'greedy'})")
-    # and the run actually observed something
+    # and the run actually observed something, down to the engine's spans
     snap = obs.metrics.snapshot()
     assert snap["sched_requests_finished_total"]["value"] == 8
     assert snap["sched_ticks_total"]["value"] > 0
     assert obs.slo.summary()["requests"] == 8
+    spans = {e["name"] for e in obs.tracer.events}
+    assert {"engine.inputs", "engine.launch", "engine.outputs"} <= spans
 
 
 def test_null_obs_is_shared_and_stateless(rng, obs_engine):
