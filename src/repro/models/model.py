@@ -254,6 +254,25 @@ class Model:
     # ------------------------------------------------------------------
     # blocks
     # ------------------------------------------------------------------
+    def _ragged_on_pallas(self) -> bool:
+        """Whether mixed_step's attention runs the Pallas ragged kernel
+        (else ``layers.ragged_paged_attention_decode``, in XLA)."""
+        return self.opts.attn_impl == "pallas" and not self.cfg.logit_softcap
+
+    def ragged_walk(self, token_rows, token_pos, *, block_size: int,
+                    npages: int):
+        """(runs, KV steps) that the Pallas ragged kernel walks in each
+        attention layer of mixed_step for this packed token list
+        (``ragged_plan``), or None where mixed_step does not run it."""
+        if not self._ragged_on_pallas():
+            return None
+        from repro.kernels.decode_attention import ragged_plan
+        cfg = self.cfg
+        return ragged_plan(token_rows, token_pos, block_size=block_size,
+                           kv_heads=cfg.num_kv_heads,
+                           q_per_kv=cfg.num_heads // cfg.num_kv_heads,
+                           head_dim=cfg.head_dim, npages=npages)
+
     def _attention(self, bp, h_in, positions, peft, peft_u, cache_u, decode_pos,
                    prompt_len, block_tables=None, token_rows=None):
         cfg, opts = self.cfg, self.opts
@@ -296,7 +315,7 @@ class Model:
             off = pos % bs_page
             kc = cache_u["k"].at[page, off].set(k[:, 0].astype(cache_u["k"].dtype))
             vc = cache_u["v"].at[page, off].set(v[:, 0].astype(cache_u["v"].dtype))
-            if opts.attn_impl == "pallas" and not softcap:
+            if self._ragged_on_pallas():
                 from repro.kernels import ops as kops
                 o = kops.ragged_paged_attention(q[:, 0], kc, vc, block_tables,
                                                 token_rows, decode_pos)[:, None]

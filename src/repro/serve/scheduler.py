@@ -1425,8 +1425,14 @@ class ContinuousScheduler:
                     t += n
             sample = (self.slot_temps, self.slot_topk, self.slot_topp,
                       self.slot_keys, self.slot_steps)
+            span = {"tokens": int(t), "width": T}
+            walk = tr.enabled and self.engine.model.ragged_walk(
+                token_rows, token_pos, block_size=self.pool.block_size,
+                npages=self.pool.block_tables.shape[1])
+            if walk:
+                span["attn_runs"], span["attn_kv_steps"] = walk
             try:
-                with tr.span("dispatch", tokens=int(t), width=T):
+                with tr.span("dispatch", **span):
                     toks, logits, cache, finite = self.engine.serve_step(
                         tokens, token_rows, token_pos, logit_idx,
                         self.pool.cache, self.pool.block_tables,

@@ -99,6 +99,28 @@ def test_ragged_kernel_keeps_its_trace_name(one_chip):
                          for c in calls), calls
 
 
+@pytest.mark.parametrize("width", [SLOTS, SLOTS - 1 + CHUNK])
+@pytest.mark.parametrize("arch", ["smollm-360m", "olmo-1b"])
+def test_ragged_kernel_walk_compiles_at_model_widths(one_chip, arch, width):
+    """The query-block kernel at each configuration's widths (smollm:
+    15 heads over 5 KV heads of 64, pages packed two keys a 128-lane row;
+    olmo: 16 KV heads of 128, one key a row): Mosaic takes its DMAs and
+    its VMEM (it refuses a kernel over the default scoped VMEM limit,
+    which the kernel does not raise), and the only TPU kernel in the
+    program is ``%ragged_paged_attention.<n>``."""
+    cfg = configs.get(arch)
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    page = ((NUM_BLOCKS, BLOCK, cfg.num_kv_heads, cfg.head_dim), bf16)
+    compiled = _compile(ragged_paged_attention_kernel, one_chip,
+                        ((width, cfg.num_heads, cfg.head_dim), bf16), page,
+                        page, ((SLOTS, NPAGES), i32), ((width,), i32),
+                        ((width,), i32))
+    calls = [line.strip() for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1, calls
+    assert re.match(r"%ragged_paged_attention\.\d+ = ", calls[0]), calls
+
+
 def test_flash_kernel_compiles(one_chip):
     bf16 = jnp.bfloat16
     _compile(flash_attention_kernel, one_chip, ((1, 1024, H, HD), bf16),

@@ -23,6 +23,8 @@ import numpy as np
 import pytest
 
 from repro.core import aot as A
+from repro.kernels.decode_attention import ragged_plan
+from repro.models.model import Model, ModelOptions
 from repro.obs import NULL_TRACER, ServeObservability
 from repro.obs.tracing import TickTracer
 from repro.serve.engine import ServeConfig, ServeEngine, finite_rows
@@ -92,6 +94,61 @@ def test_engine_spans_nest_in_dispatch_in_order(engine):
             assert a["ts"] + a["dur"] <= b["ts"] + 1e-3
     # and no engine span falls outside a dispatch
     assert len(engine_spans) == 3 * len(dispatches)
+
+
+def test_dispatch_span_counts_the_kernel_walk(tiny_lm, monkeypatch):
+    """Each dispatch span carries what the Pallas ragged kernel walks for
+    that tick's packed list (``ragged_plan``): its runs, and its KV steps
+    over all KV heads. A tick of one token per slot is one run per
+    token."""
+    cfg, _, params = tiny_lm
+    model = Model(cfg, ModelOptions(chunk_q=8, chunk_kv=8, mlstm_chunk=4,
+                                    attn_impl="pallas"))
+    eng = ServeEngine(model, params, ServeConfig(max_len=MAX_LEN),
+                      fused_tasks=[A.random_fused(cfg, params["embed"]["tok"],
+                                                  seed=s) for s in range(2)])
+    packed = []
+    serve_step = eng.serve_step
+
+    def spy(tokens, token_rows, token_pos, *rest):
+        packed.append((np.array(token_rows), np.array(token_pos)))
+        return serve_step(tokens, token_rows, token_pos, *rest)
+
+    monkeypatch.setattr(eng, "serve_step", spy)
+    obs = ServeObservability(metrics=False, trace=True)
+    sched = _serve(eng, _requests(cfg, 3), obs)
+    spans = [e for e in obs.tracer.events
+             if e["ph"] == "X" and e["name"] == "dispatch"]
+    assert len(spans) == len(packed) > 0
+    one_per_slot = 0
+    for ev, (rows, pos) in zip(spans, packed):
+        runs, steps = ragged_plan(rows, pos, block_size=BLOCK,
+                                  kv_heads=cfg.num_kv_heads,
+                                  q_per_kv=cfg.num_heads // cfg.num_kv_heads,
+                                  head_dim=cfg.head_dim,
+                                  npages=sched.pool.block_tables.shape[1])
+        assert ev["args"]["attn_runs"] == runs
+        assert ev["args"]["attn_kv_steps"] == steps
+        live = rows[pos >= 0]
+        if len(set(live)) == len(live):
+            one_per_slot += 1
+            assert runs == len(live)
+    assert one_per_slot > 0
+
+
+def test_dispatch_span_has_no_walk_off_the_kernel(engine):
+    """Where mixed_step's attention runs in XLA (the fixture's chunked
+    attention), the dispatch spans count no kernel walk."""
+    cfg, eng = engine
+    obs = ServeObservability(metrics=False, trace=True)
+    _serve(eng, _requests(cfg, 2), obs)
+    spans = [e for e in obs.tracer.events
+             if e["ph"] == "X" and e["name"] == "dispatch"]
+    assert spans
+    for ev in spans:
+        assert "attn_runs" not in ev["args"]
+        assert "attn_kv_steps" not in ev["args"]
+        assert ev["args"]["tokens"] > 0
 
 
 def test_untraced_scheduler_detaches_the_engine_tracer(engine):

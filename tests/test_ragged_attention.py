@@ -27,7 +27,9 @@ import pytest
 
 from repro.core import aot as A
 from repro.kernels import ref as R
-from repro.kernels.decode_attention import ragged_paged_attention_kernel
+from repro.kernels.decode_attention import (_ragged_blocks, _run_metadata,
+                                            ragged_paged_attention_kernel,
+                                            ragged_plan)
 from repro.models.layers import ragged_paged_attention_decode
 from repro.serve.engine import ServeConfig, ServeEngine
 from repro.serve.scheduler import (ContinuousScheduler, Request,
@@ -46,27 +48,44 @@ def _tables_for(rng, ns, bs, nb, depths):
 
 
 # every composition a tick can pack: (token_rows, token_pos) over 4 slots
-# (a token at pos p attends to its slot's kv [0, p]; -1 = dead padding)
+# (a token at pos p attends to its slot's kv [0, p]; -1 = dead padding),
+# and the widths it runs at: (heads, kv heads, head_dim, block_size,
+# pool pages)
+SMALL = (4, 2, 16, 8, 40)
+SMOLLM = (6, 2, 64, 16, 96)       # smollm-360m's g=3, hd 64, 16-token pages
+OLMO = (2, 2, 128, 16, 40)        # olmo-1b's g=1, hd 128
 COMPOSITIONS = {
-    "decode_only": ([0, 1, 2, 3], [13, 5, 0, 26]),
-    "prefill_only": ([1, 1, 1, 1, 1, 1], [0, 1, 2, 3, 4, 5]),
-    "mixed": ([0, 2, 1, 1, 1, 1, 3], [13, 3, 5, 6, 7, 8, 0]),
-    "dead_tokens": ([1, 0, 0, 0], [9, -1, -1, -1]),
-    "straddle_pages": ([0, 2, 2, 2, 2, 2, 2, 3], [7, 5, 6, 7, 8, 9, 10, 30]),
+    "decode_only": ([0, 1, 2, 3], [13, 5, 0, 26], SMALL),
+    "prefill_only": ([1, 1, 1, 1, 1, 1], [0, 1, 2, 3, 4, 5], SMALL),
+    "mixed": ([0, 2, 1, 1, 1, 1, 3], [13, 3, 5, 6, 7, 8, 0], SMALL),
+    "dead_tokens": ([1, 0, 0, 0], [9, -1, -1, -1], SMALL),
+    "straddle_pages": ([0, 2, 2, 2, 2, 2, 2, 3], [7, 5, 6, 7, 8, 9, 10, 30],
+                       SMALL),
     # several prefills' chunks packed in one tick (the multi-prefill
     # scheduler), sharing the budget around decode rows and dead padding
-    "two_chunks": ([0, 1, 1, 1, 2, 2, 3], [13, 0, 1, 2, 4, 5, 26]),
+    "two_chunks": ([0, 1, 1, 1, 2, 2, 3], [13, 0, 1, 2, 4, 5, 26], SMALL),
     "three_chunks_dead": ([1, 1, 0, 2, 2, 3, 3, 0],
-                          [3, 4, 9, 0, 1, 16, 17, -1]),
+                          [3, 4, 9, 0, 1, 16, 17, -1], SMALL),
+    # a 200-token chunk that starts mid-block (after a decode row) and
+    # runs on past the first of two 112-token query blocks
+    "run_over_blocks": ([0] + [1] * 200, [40] + list(range(20, 220)),
+                        SMOLLM),
+    # depths of 700 and 693: two KV steps, of 32 pages and of 12 pages
+    # whose last is partial, beside a shallow run
+    "deep_steps": ([0, 1, 1, 1, 2], [699, 690, 691, 692, 40], SMOLLM),
+    # decode runs of four slots in one query block
+    "decode_shared_block": ([3, 1, 0, 2], [33, 250, 17, 5], SMOLLM),
+    "all_dead": ([0, 0, 0, 0], [-1, -1, -1, -1], SMOLLM),
+    # g=1: slot 0's 19 pages take two KV steps of 16 pages
+    "olmo_mixed": ([0, 1, 2, 2, 2, 3, 0], [299, 3, 30, 31, 32, 0, -1], OLMO),
 }
 
 
 @pytest.mark.parametrize("comp", sorted(COMPOSITIONS))
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_ragged_kernel_matches_oracle(rng, comp, dtype):
-    rows, pos = COMPOSITIONS[comp]
-    ns, h, kvh, hd, bs, nb = 4, 4, 2, 16, 8, 40
-    T = len(rows)
+    rows, pos, (h, kvh, hd, bs, nb) = COMPOSITIONS[comp]
+    ns, T = 4, len(rows)
     t = lambda *sh: jnp.asarray(rng.normal(size=sh), dtype)
     q, kp, vp = t(T, h, hd), t(nb, bs, kvh, hd), t(nb, bs, kvh, hd)
     rows_j = jnp.asarray(rows, jnp.int32)
@@ -120,6 +139,100 @@ def test_ragged_decode_token_equals_paged_decode(rng):
     paged = R.paged_decode_attention_ref(q, kp, vp, bt, pos + 1)
     np.testing.assert_allclose(np.asarray(ragged), np.asarray(paged),
                                atol=1e-6, rtol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# ragged_plan: what the kernel walks
+# ---------------------------------------------------------------------------
+
+# smollm-360m as the benchmark serves it: 15 heads over 5 KV heads of 64,
+# 16-token pages, 128 page slots (max_len 2048)
+SMOLLM_PLAN = dict(block_size=16, kv_heads=5, q_per_kv=3, head_dim=64,
+                   npages=128)
+
+
+def test_ragged_plan_counts_decode_tick():
+    """T=8, one decode token per slot: eight one-token runs in one query
+    block. A 64-lane page is 8 rows of 128 lanes, so a KV step is 32
+    pages (512 keys): depths 300 ... 1900 take 19, 29, 38, 50, 63, 82,
+    100, 119 pages, i.e. 1+1+2+2+2+3+4+4 = 19 steps per KV head, where
+    the page-slot grid it replaces took 8 * 5 * 128 = 5120 steps."""
+    depths = [300, 450, 600, 800, 1000, 1300, 1600, 1900]
+    assert _ragged_blocks(8, 3, 64, 16, 128) == (8, 1, 32)
+    runs, steps = ragged_plan(list(range(8)), [d - 1 for d in depths],
+                              **SMOLLM_PLAN)
+    assert runs == 8
+    assert steps == 5 * 19
+
+
+def test_ragged_plan_counts_chunk_tick():
+    """T=263: 7 decode rows, then a 256-token chunk at positions
+    1024-1279. Three balanced query blocks of 96 tokens (288 query rows):
+    the seven decode runs plus the chunk's first 89 tokens, then 96 and
+    71 chunk tokens: 10 runs. The chunk's pieces reach depths 1113, 1209
+    and 1280 (70, 76, 80 pages: 3 steps each); the decode rows at depths
+    300 ... 1600 take 1+1+2+2+2+3+4 = 15 steps. The page-slot grid it
+    replaces took 263 * 5 * 128 = 168,320 steps."""
+    dec = [300, 450, 600, 800, 1000, 1300, 1600]
+    rows = list(range(7)) + [7] * 256
+    pos = [d - 1 for d in dec] + list(range(1024, 1280))
+    assert _ragged_blocks(263, 3, 64, 16, 128)[:2] == (96, 3)
+    runs, steps = ragged_plan(rows, pos, **SMOLLM_PLAN)
+    assert runs == 10
+    _, _, depth, first = _run_metadata(np, np.asarray(rows),
+                                       np.asarray(pos), 96, 288)
+    np.testing.assert_array_equal(first, [0, 8, 9, 10])
+    np.testing.assert_array_equal(depth[7:runs], [1113, 1209, 1280])
+    assert steps == 5 * (15 + 3 * 3)
+
+
+def test_ragged_plan_all_dead_walks_nothing():
+    assert ragged_plan([0, 1, 2], [-1, -1, -1], **SMOLLM_PLAN) == (0, 0)
+
+
+def _runs_by_hand(rows, pos, tq, t_pad):
+    """Each token's run, each run's slot and depth, each block's first
+    run: a token starts a run unless it follows a live token of its own
+    slot in the same query block."""
+    run, slot, depth = [], [], []
+    for t in range(t_pad):
+        p = pos[t] if t < len(pos) else -1
+        if p < 0:
+            run.append(-1)
+            continue
+        if t % tq and run[-1] >= 0 and rows[t - 1] == rows[t]:
+            depth[-1] = max(depth[-1], p + 1)
+        else:
+            slot.append(rows[t])
+            depth.append(p + 1)
+        run.append(len(slot) - 1)
+    first = [len({r for r in run[:b * tq] if r >= 0})
+             for b in range(t_pad // tq + 1)]
+    return run, slot, depth, first
+
+
+@pytest.mark.parametrize("comp", sorted(COMPOSITIONS))
+def test_ragged_plan_matches_kernel_metadata(comp):
+    """The runs the host plan counts (numpy) are the ones the kernel's
+    wrapper derives inside the jit (jax.numpy), and the ones found by
+    hand: each token's run, each run's slot and depth, and each query
+    block's first run."""
+    rows, pos, (h, kvh, hd, bs, _) = COMPOSITIONS[comp]
+    tq, nqb, _ = _ragged_blocks(len(rows), h // kvh, hd, bs, 8)
+    t_pad = tq * nqb
+    run, slot, depth, first = _runs_by_hand(rows, pos, tq, t_pad)
+    host = _run_metadata(np, np.asarray(rows), np.asarray(pos), tq, t_pad)
+    dev = jax.jit(_run_metadata, static_argnums=(0, 3, 4))(
+        jnp, jnp.asarray(rows, jnp.int32), jnp.asarray(pos, jnp.int32),
+        tq, t_pad)
+    n = len(slot)
+    for got in (host, dev):
+        np.testing.assert_array_equal(np.asarray(got[0]), run)
+        np.testing.assert_array_equal(np.asarray(got[1])[:n], slot)
+        np.testing.assert_array_equal(np.asarray(got[2])[:n], depth)
+        np.testing.assert_array_equal(np.asarray(got[3]), first)
+    assert ragged_plan(rows, pos, block_size=bs, kv_heads=kvh,
+                       q_per_kv=h // kvh, head_dim=hd, npages=8)[0] == n
 
 
 # ---------------------------------------------------------------------------
