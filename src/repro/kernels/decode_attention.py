@@ -318,10 +318,12 @@ def _run_metadata(xp, token_rows, token_pos, tq: int, t_pad: int):
 
 def ragged_plan(token_rows, token_pos, *, block_size: int, kv_heads: int,
                 q_per_kv: int, head_dim: int,
-                npages: int) -> Tuple[int, int]:
-    """(runs, KV steps over all KV heads) that one launch of the ragged
-    kernel (one layer) walks for a packed token list: each run walks its
-    slot's pages up to its deepest token, several pages a KV step."""
+                npages: int) -> Tuple[int, int, int]:
+    """(runs, KV steps over all KV heads, query rows) that one launch of
+    the ragged kernel (one layer) walks for a packed token list: each run
+    walks its slot's pages up to its deepest token, several pages a KV
+    step; the programs hold, over all KV heads, every query block's
+    tokens (live or padding) times ``q_per_kv`` query heads."""
     pos = np.asarray(token_pos)
     tq, nqb, ppk = _ragged_blocks(len(pos), q_per_kv, head_dim, block_size,
                                   npages)
@@ -329,7 +331,8 @@ def ragged_plan(token_rows, token_pos, *, block_size: int, kv_heads: int,
                                              pos, tq, tq * nqb)
     runs = int(block_first[-1])
     pages = -(-depth[:runs] // block_size)
-    return runs, kv_heads * int((-(-pages // ppk)).sum())
+    return (runs, kv_heads * int((-(-pages // ppk)).sum()),
+            kv_heads * nqb * tq * q_per_kv)
 
 
 def _ragged_kernel(slot_ref, depth_ref, first_ref, bt_ref,

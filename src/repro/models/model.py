@@ -261,9 +261,9 @@ class Model:
 
     def ragged_walk(self, token_rows, token_pos, *, block_size: int,
                     npages: int):
-        """(runs, KV steps) that the Pallas ragged kernel walks in each
-        attention layer of mixed_step for this packed token list
-        (``ragged_plan``), or None where mixed_step does not run it."""
+        """(runs, KV steps, query rows) that the Pallas ragged kernel
+        walks in each attention layer of mixed_step for this packed token
+        list (``ragged_plan``), or None where mixed_step does not run it."""
         if not self._ragged_on_pallas():
             return None
         from repro.kernels.decode_attention import ragged_plan

@@ -1430,7 +1430,8 @@ class ContinuousScheduler:
                 token_rows, token_pos, block_size=self.pool.block_size,
                 npages=self.pool.block_tables.shape[1])
             if walk:
-                span["attn_runs"], span["attn_kv_steps"] = walk
+                (span["attn_runs"], span["attn_kv_steps"],
+                 span["attn_q_rows"]) = walk
             try:
                 with tr.span("dispatch", **span):
                     toks, logits, cache, finite = self.engine.serve_step(

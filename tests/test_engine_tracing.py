@@ -96,11 +96,11 @@ def test_engine_spans_nest_in_dispatch_in_order(engine):
     assert len(engine_spans) == 3 * len(dispatches)
 
 
-def test_dispatch_span_counts_the_kernel_walk(tiny_lm, monkeypatch):
-    """Each dispatch span carries what the Pallas ragged kernel walks for
-    that tick's packed list (``ragged_plan``): its runs, and its KV steps
-    over all KV heads. A tick of one token per slot is one run per
-    token."""
+@pytest.fixture(scope="module")
+def pallas_served(tiny_lm):
+    """Requests served by an engine whose mixed_step runs the Pallas
+    ragged kernel, traced: the config, the dispatch spans, each dispatch's
+    packed (token_rows, token_pos) and the scheduler."""
     cfg, _, params = tiny_lm
     model = Model(cfg, ModelOptions(chunk_q=8, chunk_kv=8, mlstm_chunk=4,
                                     attn_impl="pallas"))
@@ -114,19 +114,27 @@ def test_dispatch_span_counts_the_kernel_walk(tiny_lm, monkeypatch):
         packed.append((np.array(token_rows), np.array(token_pos)))
         return serve_step(tokens, token_rows, token_pos, *rest)
 
-    monkeypatch.setattr(eng, "serve_step", spy)
+    eng.serve_step = spy
     obs = ServeObservability(metrics=False, trace=True)
     sched = _serve(eng, _requests(cfg, 3), obs)
     spans = [e for e in obs.tracer.events
              if e["ph"] == "X" and e["name"] == "dispatch"]
+    return cfg, spans, packed, sched
+
+
+def test_dispatch_span_counts_the_kernel_walk(pallas_served):
+    """Each dispatch span carries what the Pallas ragged kernel walks for
+    that tick's packed list (``ragged_plan``): its runs, and its KV steps
+    over all KV heads. A tick of one token per slot is one run per
+    token."""
+    cfg, spans, packed, sched = pallas_served
     assert len(spans) == len(packed) > 0
     one_per_slot = 0
     for ev, (rows, pos) in zip(spans, packed):
-        runs, steps = ragged_plan(rows, pos, block_size=BLOCK,
-                                  kv_heads=cfg.num_kv_heads,
-                                  q_per_kv=cfg.num_heads // cfg.num_kv_heads,
-                                  head_dim=cfg.head_dim,
-                                  npages=sched.pool.block_tables.shape[1])
+        runs, steps, _ = ragged_plan(
+            rows, pos, block_size=BLOCK, kv_heads=cfg.num_kv_heads,
+            q_per_kv=cfg.num_heads // cfg.num_kv_heads,
+            head_dim=cfg.head_dim, npages=sched.pool.block_tables.shape[1])
         assert ev["args"]["attn_runs"] == runs
         assert ev["args"]["attn_kv_steps"] == steps
         live = rows[pos >= 0]
@@ -134,6 +142,23 @@ def test_dispatch_span_counts_the_kernel_walk(tiny_lm, monkeypatch):
             one_per_slot += 1
             assert runs == len(live)
     assert one_per_slot > 0
+
+
+def test_dispatch_span_counts_query_rows(pallas_served):
+    """Each dispatch span carries the query rows the kernel's programs
+    hold in a layer. Both packed widths here (3 decode rows; 2 decode
+    rows plus an 8-token chunk) fit one query block of exactly the
+    width, so the rows are KV heads x width x query heads per KV head:
+    every head of every packed token, live or dead."""
+    cfg, spans, packed, _ = pallas_served
+    widths = set()
+    for ev, (_, pos) in zip(spans, packed):
+        args = ev["args"]
+        widths.add(args["width"])
+        assert args["width"] == len(pos)
+        assert args["tokens"] == int((pos >= 0).sum())
+        assert args["attn_q_rows"] == cfg.num_heads * args["width"]
+    assert widths == {SLOTS, SLOTS - 1 + 8}
 
 
 def test_dispatch_span_has_no_walk_off_the_kernel(engine):
@@ -148,6 +173,7 @@ def test_dispatch_span_has_no_walk_off_the_kernel(engine):
     for ev in spans:
         assert "attn_runs" not in ev["args"]
         assert "attn_kv_steps" not in ev["args"]
+        assert "attn_q_rows" not in ev["args"]
         assert ev["args"]["tokens"] > 0
 
 

@@ -159,10 +159,11 @@ def test_ragged_plan_counts_decode_tick():
     the page-slot grid it replaces took 8 * 5 * 128 = 5120 steps."""
     depths = [300, 450, 600, 800, 1000, 1300, 1600, 1900]
     assert _ragged_blocks(8, 3, 64, 16, 128) == (8, 1, 32)
-    runs, steps = ragged_plan(list(range(8)), [d - 1 for d in depths],
-                              **SMOLLM_PLAN)
+    runs, steps, q_rows = ragged_plan(list(range(8)),
+                                      [d - 1 for d in depths], **SMOLLM_PLAN)
     assert runs == 8
     assert steps == 5 * 19
+    assert q_rows == 5 * 8 * 3
 
 
 def test_ragged_plan_counts_chunk_tick():
@@ -177,8 +178,9 @@ def test_ragged_plan_counts_chunk_tick():
     rows = list(range(7)) + [7] * 256
     pos = [d - 1 for d in dec] + list(range(1024, 1280))
     assert _ragged_blocks(263, 3, 64, 16, 128)[:2] == (96, 3)
-    runs, steps = ragged_plan(rows, pos, **SMOLLM_PLAN)
+    runs, steps, q_rows = ragged_plan(rows, pos, **SMOLLM_PLAN)
     assert runs == 10
+    assert q_rows == 5 * 288 * 3
     _, _, depth, first = _run_metadata(np, np.asarray(rows),
                                        np.asarray(pos), 96, 288)
     np.testing.assert_array_equal(first, [0, 8, 9, 10])
@@ -187,7 +189,68 @@ def test_ragged_plan_counts_chunk_tick():
 
 
 def test_ragged_plan_all_dead_walks_nothing():
-    assert ragged_plan([0, 1, 2], [-1, -1, -1], **SMOLLM_PLAN) == (0, 0)
+    # the programs still hold the block's three dead tokens' query rows
+    assert ragged_plan([0, 1, 2], [-1, -1, -1], **SMOLLM_PLAN) == (
+        0, 0, 5 * 3 * 3)
+
+
+# olmo-1b as the benchmark serves it: 16 heads over 16 KV heads of 128
+# (g=1: up to 384-token query blocks; a 128-lane page is 16 rows, so a
+# KV step is 16 pages), 16-token pages, 128 page slots
+OLMO_PLAN = dict(block_size=16, kv_heads=16, q_per_kv=1, head_dim=128,
+                 npages=128)
+DECODE_DEPTHS = [300, 450, 600, 800, 1000, 1300, 1600, 1900]
+
+
+def _tick(n_decode, chunk_at=None, chunk=0, dead=0):
+    """A packed tick: n_decode decode rows at DECODE_DEPTHS, then a chunk
+    of slot 7 at positions chunk_at.., then dead padding."""
+    rows = list(range(n_decode)) + [7] * chunk + [0] * dead
+    pos = ([d - 1 for d in DECODE_DEPTHS[:n_decode]]
+           + list(range(chunk_at or 0, (chunk_at or 0) + chunk))
+           + [-1] * dead)
+    return rows, pos
+
+
+# (plan, tick, (tokens per block, blocks), runs, KV steps per KV head,
+# query rows by hand = KV heads x blocks x block tokens x q_per_kv)
+QUERY_ROWS = {
+    # 8 decode rows: one block of exactly 8 tokens, 8 rows of 3 heads
+    "smollm_decode": (SMOLLM_PLAN, _tick(8), (8, 1), 8, 19, 5 * 8 * 3),
+    # 5 decode rows and 3 free slots: the dead tokens are held too
+    "smollm_decode_free_slots": (SMOLLM_PLAN, _tick(5, dead=3), (8, 1), 5,
+                                 1 + 1 + 2 + 2 + 2, 5 * 8 * 3),
+    # 7 decode + 256 chunk: three blocks of 96 (288 tokens, 25 padding)
+    "smollm_chunk": (SMOLLM_PLAN, _tick(7, 1024, 256), (96, 3), 10, 24,
+                     5 * 288 * 3),
+    # g=1: the same decode tick is one block of 8 one-head rows per KV
+    # head; depths take 19 ... 119 pages, 2+2+3+4+4+6+7+8 steps of 16
+    "olmo_decode": (OLMO_PLAN, _tick(8), (8, 1), 8, 36, 16 * 8 * 1),
+    # 7 decode + 256 chunk: 263 tokens fit one 384-token block, no
+    # rounding; the chunk reaches depth 1280 (80 pages, 5 steps)
+    "olmo_chunk": (OLMO_PLAN, _tick(7, 1024, 256), (263, 1), 8, 28 + 5,
+                   16 * 263 * 1),
+    # 400 tokens: two balanced blocks of 208 (416 tokens, 16 padding);
+    # the chunk splits at token 208, its pieces reach depths 201 and 393
+    "olmo_two_blocks": (OLMO_PLAN, _tick(7, 0, 393), (208, 2), 9,
+                        28 + 1 + 2, 16 * 416 * 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(QUERY_ROWS))
+def test_ragged_plan_counts_query_rows(case):
+    """The query rows the kernel's programs hold in one layer, counted
+    by hand for smollm-360m's (g=3, hd 64) and olmo-1b's (g=1, hd 128)
+    blocks, with the runs and KV steps of the same ticks."""
+    plan, (rows, pos), blocks, want_runs, steps_per_head, want_rows = \
+        QUERY_ROWS[case]
+    assert _ragged_blocks(len(pos), plan["q_per_kv"], plan["head_dim"],
+                          plan["block_size"], plan["npages"])[:2] == blocks
+    runs, steps, q_rows = ragged_plan(rows, pos, **plan)
+    assert (runs, steps, q_rows) == (
+        want_runs, plan["kv_heads"] * steps_per_head, want_rows)
+    live = sum(p >= 0 for p in pos)
+    assert live * plan["kv_heads"] * plan["q_per_kv"] <= q_rows
 
 
 def _runs_by_hand(rows, pos, tq, t_pad):
