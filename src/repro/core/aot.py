@@ -12,9 +12,9 @@ rows the batch needs (paper §3.3):
   * Kronecker: P = (W_L ⊗ W_M) W_R                        (paper Eq. 2)
 
 After training, :func:`fuse` materializes the explicit per-layer tables so
-inference is a single gather+add per layer (zero extra matmuls — the paper's
-"zero-cost" property), and :func:`stack_tasks` builds the multi-task table
-set a single frozen backbone serves from.
+inference is one row gather for all layers plus an add per layer (zero
+extra matmuls — the paper's "zero-cost" property), and :func:`stack_tasks`
+builds the multi-task table set a single frozen backbone serves from.
 
 Initialization follows the paper §4.1: FC — W1 random, W2/b1/b2 zero;
 Kronecker — W_L/W_M random, W_R zero. Both make the initial bias exactly 0,
@@ -118,9 +118,25 @@ def rows_kron(layer_p, ids, opt: AoTOptions, vocab: int, dtype=jnp.float32,
     return out
 
 
-def rows_fused(layer_p, ids, dtype=jnp.float32):
-    """Inference path: gather rows of the fused table. layer_p: {"table": (V, d)}."""
-    return jnp.take(layer_p["table"].astype(dtype), ids, axis=0)
+def rows_fused_layers(table, ids, task_ids, *, start: int, count: int,
+                      dtype=jnp.float32):
+    """Inference path: the fused-table rows of layers ``start`` to
+    ``start + count`` in ONE gather, ahead of those layers — the bias
+    depends on (layer, task, token) only, never on the hidden state.
+
+    table: (L, V, d) (task_ids unread), or (L, tasks, V, d) with task_ids
+    (b,) for multi-task serving; ids: (b, s) -> (count, b, s, d). The
+    table is read as flat (L·tasks·V, d) rows at ``(layer·tasks + task)·V
+    + id``, so only the rows the tokens name are read and no layer's
+    table is sliced out.
+    """
+    n_layers = table.shape[0]
+    V, d = table.shape[-2:]
+    tasks = table.shape[1] if table.ndim == 4 else 1
+    row = ids if table.ndim == 3 else task_ids[:, None] * V + ids
+    layers = jnp.arange(start, start + count, dtype=row.dtype)
+    idx = layers.reshape((count,) + (1,) * row.ndim) * (tasks * V) + row
+    return table.reshape(n_layers * tasks * V, d)[idx].astype(dtype)
 
 
 def rows_fused_multitask(table_layer, task_ids, ids, dtype=jnp.float32):
@@ -217,7 +233,8 @@ def write_block(stack, block, layer: int, task: int):
 def stack_tasks(fused_list, dtype=None):
     """[{'table': (L, V, d)}, ...] per task -> {'table': (L, T, V, d)}.
 
-    Layer-major so the model's per-layer scan slicing sees (T, V, d) slices.
+    Layer-major: :func:`rows_fused_layers` reads it as flat (layer, task,
+    token) rows.
     Each task is written into a preallocated stack (``dtype``, default the
     first table's), so beyond the caller's list the device holds the stack
     and nothing else; tables kept on the host (a loaded checkpoint) cost

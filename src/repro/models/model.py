@@ -3,7 +3,8 @@
 Layers are grouped by *pattern unit* (configs.base); each group is a
 homogeneous stack scanned with ``jax.lax.scan`` (stacked params on axis 0),
 optionally rematerialized per unit. PEFT hooks (AoT P-Tuning + baselines)
-are threaded through the scan as per-layer slices.
+are threaded through the scan as per-layer slices; a fused AoT table as its
+bias rows, gathered for all layers of a group ahead of the scan.
 
 Caches: every block kind owns a decode cache (attention KV — ring-buffered
 for SWA so a 512k-token decode holds only the window; RG-LRU conv+state;
@@ -211,14 +212,18 @@ class Model:
     # ------------------------------------------------------------------
     # PEFT per-layer machinery
     # ------------------------------------------------------------------
-    def _peft_group_xs(self, peft, plan: GroupPlan):
-        """Slice per-layer PEFT leaves into (R, U, ...) for the scan."""
+    def _peft_group_xs(self, peft, plan: GroupPlan, ids):
+        """Slice per-layer PEFT leaves into (R, U, ...) for the scan. A fused
+        AoT table gives the group's bias rows instead, gathered here for all
+        its layers at once: the scan never sees the table."""
         if peft is None:
             return None
         method = peft["method"]
         pp = peft["params"]
         take = None
         if method == "aot":
+            if peft["opt"].aot.mode == "fused":
+                return self._aot_group_rows(peft, plan, ids)
             take = pp["aot"]
         elif method == "bitfit":
             take = {k: v for k, v in pp["bitfit"].items() if k != "final"}
@@ -233,8 +238,33 @@ class Model:
         return jax.tree.map(
             lambda x: _regroup(x, plan.start, plan.repeats, len(plan.kinds)), take)
 
+    def _aot_group_rows(self, peft, plan: GroupPlan, ids):
+        """Every layer's fused-table bias rows of one group, (R, U, b, s, d),
+        in one gather ahead of its layers (the rows depend on layer, task
+        and token only), or None where there are no token ids."""
+        if ids is None:
+            return None
+        ulen = len(plan.kinds)
+        with jax.named_scope("aot_bias"):
+            rows = aot_mod.rows_fused_layers(
+                peft["params"]["aot"]["table"], ids, peft.get("task_ids"),
+                start=plan.start, count=plan.repeats * ulen,
+                dtype=self.opts.compute_dtype)
+        return rows.reshape((plan.repeats, ulen) + rows.shape[1:])
+
+    def aot_rows(self, peft, width: int) -> Optional[int]:
+        """Bias rows a step over ``width`` packed tokens gathers ahead of
+        its layers (layers x width), or None where it runs no fused AoT
+        table."""
+        if (peft is None or peft["method"] != "aot"
+                or peft["opt"].aot.mode != "fused"):
+            return None
+        return sum(p.repeats * len(p.kinds) for p in self.plan) * width
+
     def _aot_bias(self, peft, peft_u, ids, e_rows, rng_layer):
-        """Compute the paper's P^i rows for this layer. Returns (b, s, d) or None."""
+        """The paper's P^i rows for this layer, (b, s, d), or None. A fused
+        table's rows were gathered before the layer scan
+        (``_aot_group_rows``) and arrive as ``peft_u``."""
         if ids is None and e_rows is None:
             return None
         opt: peft_mod.PEFTOptions = peft["opt"]
@@ -245,10 +275,7 @@ class Model:
         if ao.mode == "kron":
             return aot_mod.rows_kron(peft_u, ids, ao, self.cfg.vocab_size, dt, rng_layer)
         if ao.mode == "fused":
-            tbl = peft_u["table"]
-            if tbl.ndim == 3:        # (tasks, V, d): multi-task serving
-                return aot_mod.rows_fused_multitask(tbl, peft["task_ids"], ids, dt)
-            return aot_mod.rows_fused(peft_u, ids, dt)
+            return peft_u
         raise ValueError(ao.mode)
 
     # ------------------------------------------------------------------
@@ -530,7 +557,7 @@ class Model:
                      block_tables=None, token_rows=None):
         opts = self.opts
         U = len(plan.kinds)
-        peft_xs = self._peft_group_xs(peft, plan)          # (R, U, ...) or None
+        peft_xs = self._peft_group_xs(peft, plan, ids)     # (R, U, ...) or None
 
         def unit_body(h, bp_r, peft_r, cache_r, layer_base):
             auxs = []

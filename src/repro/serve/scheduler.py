@@ -1432,6 +1432,10 @@ class ContinuousScheduler:
             if walk:
                 (span["attn_runs"], span["attn_kv_steps"],
                  span["attn_q_rows"]) = walk
+            aot_rows = tr.enabled and self.engine.model.aot_rows(
+                self.engine.peft, T)
+            if aot_rows:
+                span["aot_rows"] = aot_rows
             try:
                 with tr.span("dispatch", **span):
                     toks, logits, cache, finite = self.engine.serve_step(

@@ -155,3 +155,100 @@ def test_bitfit_is_constant_row_special_case(rng, tiny_lm):
     lg_perm, _ = model.logits(params, batch,
                               P.make({"aot": {"table": table_perm}}, fopt))
     np.testing.assert_allclose(np.asarray(lg_aot), np.asarray(lg_perm), atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# fused rows: one gather ahead of the layers == a gather before each layer
+# ---------------------------------------------------------------------------
+
+class _PerLayerTable(Model):
+    """The per-layer lookup the hoisted gather replaced: each layer of the
+    scan takes its own table, (tasks, V, d) or (V, d), as a per-layer
+    slice and gathers its rows, ``rows_fused_multitask(table[l], ...)``
+    added before layer ``l``."""
+
+    def _peft_group_xs(self, peft, plan, ids):
+        tbl = peft["params"]["aot"]["table"]
+        n = plan.repeats * len(plan.kinds)
+        return tbl[plan.start:plan.start + n].reshape(
+            (plan.repeats, len(plan.kinds)) + tbl.shape[1:])
+
+    def _aot_bias(self, peft, peft_u, ids, e_rows, rng_layer):
+        if peft_u.ndim == 2:                # (V, d): one task
+            peft_u = peft_u[None]
+            task_ids = jnp.zeros(ids.shape[0], jnp.int32)
+        else:
+            task_ids = peft["task_ids"]
+        return A.rows_fused_multitask(peft_u, task_ids, ids,
+                                      self.opts.compute_dtype)
+
+
+def _step_logits(model, params, peft, entry, rng, cfg):
+    """Logits of one call of ``entry`` over fixed random inputs (the rng
+    is fresh per model, so both models see the same ones)."""
+    ids = lambda *sh: jnp.asarray(rng.integers(0, cfg.vocab_size, sh),
+                                  jnp.int32)
+    if entry == "prefill":
+        lg, _, _ = model.prefill(params, {"tokens": ids(3, 12)}, peft,
+                                 max_len=16)
+        return lg
+    if entry == "decode_step":
+        lg, _ = model.decode_step(params, ids(3, 1),
+                                  jnp.asarray([5, 0, 9], jnp.int32),
+                                  model.init_cache(3, 16), peft)
+        return lg
+    # a mixed tick: slot 0 decodes, slot 1 runs a 4-token chunk from 0,
+    # slot 2 decodes; one dead padding token
+    bt = jnp.asarray([[1, 2, 0, 0], [3, 4, 0, 0], [5, 6, 0, 0]], jnp.int32)
+    rows = jnp.asarray([0, 1, 1, 1, 1, 2, 0], jnp.int32)
+    pos = jnp.asarray([6, 0, 1, 2, 3, 2, -1], jnp.int32)
+    lg, _ = model.mixed_step(params, ids(7, 1), rows, pos,
+                             model.init_paged_cache(8, 4), peft,
+                             block_tables=bt,
+                             logit_idx=jnp.asarray([0, 4, 5], jnp.int32))
+    return lg
+
+
+@pytest.mark.parametrize("entry", ["mixed_step", "decode_step", "prefill"])
+@pytest.mark.parametrize("scan_layers", [True, False])
+@pytest.mark.parametrize("tasks", [0, 3], ids=["one-task", "multi-task"])
+def test_hoisted_rows_bitwise_per_layer_table(rng, tiny_lm, entry,
+                                              scan_layers, tasks):
+    """Every layer's rows gathered once, ahead of the layer scan, give the
+    same logits bit for bit as each layer gathering from its own table
+    slice: the rows are the same values, added in the same place."""
+    cfg, _, params = tiny_lm
+    shape = (cfg.num_layers,) + ((tasks,) if tasks else ()) + (
+        cfg.vocab_size, cfg.d_model)
+    table = jnp.asarray(rng.normal(size=shape) * 0.05, jnp.float32)
+    peft = P.make({"aot": {"table": table}},
+                  P.PEFTOptions(method="aot", aot=A.AoTOptions(mode="fused")))
+    if tasks:       # mixed task ids: per row, or per packed token
+        n = 7 if entry == "mixed_step" else 3
+        peft["task_ids"] = jnp.asarray(np.arange(n) * 2 % tasks, jnp.int32)
+    opts = ModelOptions(chunk_q=8, chunk_kv=8, scan_layers=scan_layers)
+    got, want = (
+        _step_logits(cls(cfg, opts), params, peft, entry,
+                     np.random.default_rng(5), cfg)
+        for cls in (Model, _PerLayerTable))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert float(jnp.abs(got).max()) > 0
+
+
+@pytest.mark.parametrize("tasks", [0, 3], ids=["one-task", "multi-task"])
+def test_rows_fused_layers_reads_each_layers_rows(rng, tasks):
+    """The flat gather over a layer range returns, layer by layer, the
+    rows a per-layer lookup of that layer's table gives."""
+    L_, V, d = 5, 11, 4
+    shape = (L_,) + ((tasks,) if tasks else ()) + (V, d)
+    table = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    ids = jnp.asarray(rng.integers(0, V, (3, 6)), jnp.int32)
+    tids = jnp.asarray([2, 0, 1], jnp.int32) if tasks else None
+    got = A.rows_fused_layers(table, ids, tids, start=1, count=3)
+    assert got.shape == (3, 3, 6, d)
+    for i, layer in enumerate(range(1, 4)):
+        tbl = table[layer] if tasks else table[layer][None]
+        t = tids if tasks else jnp.zeros(3, jnp.int32)
+        np.testing.assert_array_equal(
+            np.asarray(got[i]),
+            np.asarray(A.rows_fused_multitask(tbl, t, ids)))

@@ -8,11 +8,14 @@
     under its own name, with its arguments as event stats;
   * the programs a tick launches have stable names: the step's match the
     benchmark's step-program pattern, the watchdog's does not;
+  * a tick's ``dispatch`` span counts the AoT bias rows its step gathers
+    (``aot_rows``) where a fused table is served;
   * ``mixed_step`` and the draw carry the named scopes an operator reads
     in a chip profile (``aot_bias``, ``attention``, ``mlp``, ``logits``,
     ``sampling``);
   * the watchdog's ``finite_rows`` launch is counted, once per tick.
 """
+import dataclasses
 import glob
 import re
 import sys
@@ -175,6 +178,34 @@ def test_dispatch_span_has_no_walk_off_the_kernel(engine):
         assert "attn_kv_steps" not in ev["args"]
         assert "attn_q_rows" not in ev["args"]
         assert ev["args"]["tokens"] > 0
+
+
+def test_dispatch_span_counts_aot_rows(engine):
+    """Each dispatch span of a fused multi-task engine carries the bias
+    rows its step gathers ahead of the layers: every layer's row for
+    every packed token, live or dead."""
+    cfg, eng = engine
+    obs = ServeObservability(metrics=False, trace=True)
+    _serve(eng, _requests(cfg, 4), obs)
+    spans = [e for e in obs.tracer.events
+             if e["ph"] == "X" and e["name"] == "dispatch"]
+    assert spans
+    for ev in spans:
+        assert ev["args"]["aot_rows"] == cfg.num_layers * ev["args"]["width"]
+
+
+def test_dispatch_span_has_no_aot_rows_without_a_table(tiny_lm):
+    """An engine that serves no fused AoT table gathers no bias rows, and
+    its dispatch spans say nothing of them."""
+    cfg, model, params = tiny_lm
+    eng = ServeEngine(model, params, ServeConfig(max_len=MAX_LEN))
+    obs = ServeObservability(metrics=False, trace=True)
+    _serve(eng, [dataclasses.replace(r, task_id=0)
+                 for r in _requests(cfg, 2)], obs)
+    spans = [e for e in obs.tracer.events
+             if e["ph"] == "X" and e["name"] == "dispatch"]
+    assert spans
+    assert all("aot_rows" not in ev["args"] for ev in spans)
 
 
 def test_untraced_scheduler_detaches_the_engine_tracer(engine):
