@@ -1,6 +1,6 @@
 """Paper §3.1 / Table 1: multi-task inference with one backbone.
 
-Four comparisons:
+Comparisons:
 
   (a) one batched multi-task pass over mixed task ids vs sequential
       per-task batches — the resource-allocation win the paper argues for;
@@ -9,17 +9,15 @@ Four comparisons:
       with heterogeneous output lengths — tokens/s;
   (c) request latency (p50/p99) under a Poisson arrival stream at varying
       offered load and task counts;
-  (d) paged vs contiguous KV at an EQUAL HBM budget — concurrent requests
-      in flight and HBM bytes per request for a short-prompt/long-max_len
-      workload (where contiguous slots waste almost the whole region);
   (e) stochastic sampling overhead — the same workload decoded greedy vs
       temperature/top-p sampled (the fused sample-in-decode-step path);
   (f) n=4 parallel samples via COW page forking vs n=4 independent
       decodes — peak KV pages (prompt pages shared, only divergent decode
       tails cost HBM);
   (g) the unified ragged mixed step (``--mixed-step`` reruns just this) —
-      the paged_equal_hbm paged workload through the one-call-per-tick
-      scheduler, recording tok/s and device dispatches per tick;
+      a short-prompt workload on a page budget of two max_len regions
+      through the one-call-per-tick scheduler, recording tok/s and device
+      dispatches per tick;
   (h) multi-prefill packing (``--multi-prefill`` reruns just this) — a
       Poisson stream mixing long and short prompts, served with
       ``max_prefills=1`` (serial chunking, the old scheduler) vs several
@@ -163,87 +161,17 @@ def _drain_tracking_peak(sched):
     peak_pages = 0
     while sched.busy():
         sched.step()
-        if sched.paged:
-            peak_pages = max(peak_pages, sched.pool.blocks_in_use())
+        peak_pages = max(peak_pages, sched.pool.blocks_in_use())
     return sched.peak_running, peak_pages
-
-
-def run_paged_equal_hbm(n_tasks=2, contig_slots=2, max_len=256, prompt=8,
-                        max_new=8, n_requests=24, block_size=16):
-    """(d) the paged-KV capacity claim: at an equal KV HBM budget, a
-    short-prompt workload sustains >= 2x the concurrent requests because
-    pages are claimed per resident token, not per slot * max_len."""
-    cfg, model, params = bench_model(d_model=128, layers=4, vocab=512, heads=4,
-                                     kv=2)
-    rng = np.random.default_rng(0)
-    tasks = [random_aot_fused(cfg, params, seed=t) for t in range(n_tasks)]
-    eng = ServeEngine(model, params, ServeConfig(max_len=max_len),
-                      fused_tasks=tasks)
-
-    # equal HBM budget: what contig_slots contiguous max_len regions cost
-    budget_tokens = contig_slots * round_kv_len(max_len)
-    num_blocks = budget_tokens // block_size + 1      # +1: scratch page 0
-    paged_slots = min(n_requests, budget_tokens // block_size)
-
-    def reqs():
-        return _requests(rng, cfg, n_requests, n_tasks, prompt,
-                         max_new, max_new)
-
-    def serve(cfg_s):
-        sched = ContinuousScheduler(eng, cfg_s)
-        for r in reqs():
-            sched.submit(r)
-        t0 = time.perf_counter()
-        peak_run, peak_pages = _drain_tracking_peak(sched)
-        dt = time.perf_counter() - t0
-        return sched, peak_run, peak_pages, sched.tokens_emitted / dt
-
-    # warm both layouts' compilations out of the measurement
-    serve(SchedulerConfig(num_slots=contig_slots, kv_layout="slots"))
-    serve(SchedulerConfig(num_slots=paged_slots, kv_layout="paged",
-                          block_size=block_size, num_blocks=num_blocks,
-                          prefill_chunk=block_size))
-
-    sc, peak_c, _, tput_c = serve(
-        SchedulerConfig(num_slots=contig_slots, kv_layout="slots"))
-    sp, peak_p, peak_pages, tput_p = serve(
-        SchedulerConfig(num_slots=paged_slots, kv_layout="paged",
-                        block_size=block_size, num_blocks=num_blocks,
-                        prefill_chunk=block_size))
-
-    bpt = sp.pool.kv_bytes_per_token()
-    hbm_budget = budget_tokens * bpt
-    hbm_per_req_c = sc.pool.alloc_len * bpt
-    hbm_per_req_p = (peak_pages * block_size * bpt) / max(peak_p, 1)
-    emit("multitask/paged_equal_hbm", 0.0,
-         f"contig_peak={peak_c} paged_peak={peak_p} "
-         f"ratio={peak_p / max(peak_c, 1):.1f}x budget_kib={hbm_budget / 1024:.0f}")
-    emit("multitask/paged_hbm_per_request", 0.0,
-         f"contig_kib={hbm_per_req_c / 1024:.1f} "
-         f"paged_kib={hbm_per_req_p / 1024:.1f}")
-    RESULTS["paged_equal_hbm"] = {
-        "kv_hbm_budget_bytes": hbm_budget,
-        "workload": {"requests": n_requests, "prompt": prompt,
-                     "max_new": max_new, "max_len": max_len,
-                     "block_size": block_size},
-        "contiguous": {"slots": contig_slots, "peak_concurrent": peak_c,
-                       "tok_per_s": round(tput_c, 1),
-                       "hbm_bytes_per_request": hbm_per_req_c},
-        "paged": {"slots": paged_slots, "usable_pages": num_blocks - 1,
-                  "peak_concurrent": peak_p, "tok_per_s": round(tput_p, 1),
-                  "hbm_bytes_per_request": round(hbm_per_req_p),
-                  "preemptions": sp.preemptions,
-                  "prefill_chunks": sp.prefill_chunks_run},
-        "concurrency_ratio": round(peak_p / max(peak_c, 1), 2)}
 
 
 def run_mixed_step(n_tasks=2, contig_slots=2, max_len=256, prompt=8,
                    max_new=8, n_requests=24, block_size=16):
-    """(g) the unified single-call tick: the same paged workload as
-    run_paged_equal_hbm, now served by the ragged mixed step (one jitted
-    serve_step per tick, prefill chunks scattered straight into pool
-    pages). Records tok/s next to the two-call paged number and the
-    realized device dispatches per scheduler tick."""
+    """(g) the unified single-call tick: a short-prompt workload on a page
+    budget of ``contig_slots`` max_len regions, served by the ragged mixed
+    step (one jitted serve_step per tick, prefill chunks scattered
+    straight into pool pages). Records tok/s and the realized device
+    dispatches per scheduler tick."""
     cfg, model, params = bench_model(d_model=128, layers=4, vocab=512, heads=4,
                                      kv=2)
     rng = np.random.default_rng(0)
@@ -260,7 +188,7 @@ def run_mixed_step(n_tasks=2, contig_slots=2, max_len=256, prompt=8,
         # page/SLO fields below straight into BENCH_serve.json
         obs = ServeObservability(metrics=True)
         sched = ContinuousScheduler(eng, SchedulerConfig(
-            num_slots=paged_slots, kv_layout="paged", block_size=block_size,
+            num_slots=paged_slots, block_size=block_size,
             num_blocks=num_blocks, prefill_chunk=block_size), obs=obs)
         reqs = _requests(rng, cfg, n_requests, n_tasks, prompt,
                          max_new, max_new)
@@ -301,13 +229,10 @@ def run_mixed_step(n_tasks=2, contig_slots=2, max_len=256, prompt=8,
         "ttft_p50_ticks": slo["ttft_ticks"]["p50"],
         "ttft_p99_ticks": slo["ttft_ticks"]["p99"],
         "tpot_p50_ticks": slo["tpot_ticks"]["p50"],
-        # same workload as paged_equal_hbm's paged arm (which also routes
-        # through the unified tick now); tok/s differences between the two
-        # entries are CPU timing noise — dispatches_per_tick and
-        # tokens_per_dispatch are the stable structural claims
-        "note": "same workload as paged_equal_hbm.paged; CPU tok/s swings "
-                "with machine load, dispatches_per_tick and "
-                "tokens_per_dispatch are load-invariant"}
+        # dispatches_per_tick and tokens_per_dispatch are the stable
+        # structural claims; tok/s is CPU timing context
+        "note": "CPU tok/s swings with machine load, dispatches_per_tick "
+                "and tokens_per_dispatch are load-invariant"}
 
 
 def run_multi_prefill(n_tasks=2, slots=8, max_len=256, block_size=16,
@@ -348,7 +273,7 @@ def run_multi_prefill(n_tasks=2, slots=8, max_len=256, block_size=16,
         # callbacks, so the reported TTFT tick values are unchanged
         obs = ServeObservability(metrics=True, check_leaks=True)
         sched = ContinuousScheduler(eng, SchedulerConfig(
-            num_slots=slots, kv_layout="paged", block_size=block_size,
+            num_slots=slots, block_size=block_size,
             prefill_chunk=budget, max_prefills=max_prefills), obs=obs)
         d0 = eng.dispatches
         t0 = time.perf_counter()
@@ -533,7 +458,7 @@ def run_prefix_cache(n_tasks=2, slots=4, n_requests=16, sys_prompt=64,
     def serve(cached):
         obs = ServeObservability(metrics=True, check_leaks=True)
         sched = ContinuousScheduler(eng, SchedulerConfig(
-            num_slots=slots, kv_layout="paged", block_size=block_size,
+            num_slots=slots, block_size=block_size,
             num_blocks=num_blocks, prefill_chunk=chunk,
             prefix_cache_pages=cache_pages if cached else 0), obs=obs)
         if cached:      # pre-warm: one short request per task retains the
@@ -650,7 +575,7 @@ def run_overload(n_tasks=2, slots=4, max_len=64, block_size=8, num_blocks=13,
     def serve():
         obs = ServeObservability(metrics=True, check_leaks=True)
         sched = ContinuousScheduler(eng, SchedulerConfig(
-            num_slots=slots, kv_layout="paged", block_size=block_size,
+            num_slots=slots, block_size=block_size,
             num_blocks=num_blocks, prefill_chunk=block_size,
             max_queue=max_queue), obs=obs)
         stream = arrivals()
@@ -759,7 +684,7 @@ def run_recovery(n_tasks=2, slots=4, max_len=64, block_size=8, num_blocks=20,
 
     def make_sched(journal=None):
         return ContinuousScheduler(eng, SchedulerConfig(
-            num_slots=slots, kv_layout="paged", block_size=block_size,
+            num_slots=slots, block_size=block_size,
             num_blocks=num_blocks, prefill_chunk=block_size),
             journal=journal)
 
@@ -881,7 +806,6 @@ def run(n_tasks=4, batch=8, prompt=32, steps=16):
     RESULTS["fused_tables_gb"] = round(gb, 4)
 
     run_continuous_vs_static()
-    run_paged_equal_hbm()
     run_mixed_step()
     run_multi_prefill()
     run_sampling_and_forking()

@@ -103,8 +103,9 @@ def main():
 
     # continuous serving: the three tasks as an online stream — requests
     # arrive staggered with their own prompt/output lengths and share the
-    # slotted KV pool; one mixed decode step advances everything in flight
-    sched = ContinuousScheduler(eng, SchedulerConfig(num_slots=2, bucket_min=8))
+    # paged KV pool; one serve step a tick advances everything in flight
+    sched = ContinuousScheduler(eng, SchedulerConfig(num_slots=2,
+                                                     prefill_chunk=8))
     arrivals = []
     for i in range(6):
         plen = int(rng.integers(4, 13))
@@ -113,7 +114,7 @@ def main():
             task_id=i % 3, max_new_tokens=int(rng.integers(2, 7)))))
     finished = sched.run_stream(arrivals)
     print(f"continuous stream: {len(finished)} requests over 2 slots in "
-          f"{sched.steps_decoded} mixed decode steps")
+          f"{sched.ticks} serve steps")
     for rid in sorted(finished):
         req = finished[rid]
         ref = eng.generate(req.prompt[None], req.max_new_tokens,
@@ -124,8 +125,9 @@ def main():
     # stochastic sampling with COW-forked parallel samples: one prompt,
     # n=3 temperature/top-p continuations from ONE prefill — the forked
     # samples share the prompt's KV pages and only pay for divergent tails
-    sched = ContinuousScheduler(eng, SchedulerConfig(num_slots=4, bucket_min=8,
-                                                     block_size=8))
+    sched = ContinuousScheduler(eng, SchedulerConfig(num_slots=4,
+                                                     block_size=8,
+                                                     prefill_chunk=8))
     req = Request(rid=0,
                   prompt=rng.integers(0, cfg.vocab_size, 12).astype(np.int32),
                   task_id=0, max_new_tokens=6,

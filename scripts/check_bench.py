@@ -7,7 +7,6 @@ serving claims that hold on any machine:
 
   * dispatches per scheduler tick == 1.00 (the unified serve_step contract);
   * tokens advanced per device dispatch (work-per-call packing efficiency);
-  * concurrency ratio at an equal KV HBM budget (the paged-KV capacity claim);
   * peak forked pages vs single-sample (the COW fork HBM claim);
   * multi-prefill queued-request TTFT tick percentiles (head-of-line fix).
 

@@ -1,34 +1,27 @@
-"""Pallas TPU flash-decode: single-token attention against a long KV cache.
+"""Pallas TPU attention against a KV cache: flash-decode and ragged paged.
 
-Decode is bandwidth-bound (the whole cache is read once per token), so the
-kernel streams the cache in ``block_k`` tiles with online-softmax state in
-VMEM scratch. The KV sequence axis is the innermost (sequential) grid axis;
-blocks past the row's ``cur_len`` are skipped with ``pl.when`` so a
-part-full cache costs only the bytes actually resident — this is what the
-decode_32k / long_500k roofline cells exercise.
+``decode_attention_kernel`` is single-token attention against a
+contiguous cache, the static batch path (``ServeEngine.generate``).
+Decode is bandwidth-bound (the whole cache is read once per token), so
+the kernel streams the cache in ``block_k`` tiles with online-softmax
+state in VMEM scratch. The KV sequence axis is the innermost (sequential)
+grid axis; blocks past the row's ``cur_len`` are skipped with ``pl.when``
+so a part-full cache costs only the bytes actually resident. ``cur_len``
+may be a scalar (homogeneous batch) or a per-row ``(b,)`` vector; the
+lengths are scalar-prefetched so each grid row masks/skips against its
+own length.
 
-``cur_len`` may be a scalar (homogeneous batch) or a per-row ``(b,)``
-vector — the continuous-batching serve path, where every KV-pool slot holds
-a request at a different depth. The lengths are scalar-prefetched so each
-grid row masks/skips against its own length with no recompilation when the
-batch composition changes.
-
-``paged_decode_attention_kernel`` is the block-table variant for the paged
-KV pool: K/V live in a global ``(num_blocks, block_size)`` page pool shared
-by all requests, and each row's scalar-prefetched block-table slice routes
-the BlockSpec index_map to that row's resident pages. Pages at or past the
-row's depth are skipped entirely, so a request costs only the pages it has
-actually mapped.
-
-``ragged_paged_attention_kernel`` generalizes the paged kernel to RAGGED
-per-slot query lengths: the batch is a PACKED token list — decode rows
-contribute one token each, every in-flight prefill a chunk of its prompt
-(several prompts' chunks pack into one launch), free slots zero — and
-every token carries its owning slot (``token_rows``) and absolute
-position (``token_pos``). Its grid is (KV heads, query blocks): a program
-holds a block of consecutive packed tokens times the query heads of one
-KV head, and walks each RUN of one slot's tokens in the block over only
-that slot's live pages, by double-buffered DMA from the pool in HBM
+``ragged_paged_attention_kernel`` is the continuous-batching serve path's
+attention. K/V live in a global ``(num_blocks, block_size)`` page pool
+shared by all requests, each slot mapping its pages through a block
+table. The batch is a PACKED token list — decode rows contribute one
+token each, every in-flight prefill a chunk of its prompt (several
+prompts' chunks pack into one launch), free slots zero — and every token
+carries its owning slot (``token_rows``) and absolute position
+(``token_pos``). Its grid is (KV heads, query blocks): a program holds a
+block of consecutive packed tokens times the query heads of one KV head,
+and walks each RUN of one slot's tokens in the block over only that
+slot's live pages, by double-buffered DMA from the pool in HBM
 (``ragged_plan`` counts the walk on the host). Chunk tokens see kv
 ``<= token_pos`` through their OWN slot's pages (causal within a chunk,
 since chunk KV is scattered before the launch; blind to other slots'
@@ -55,9 +48,9 @@ def round_kv_len(n: int, block_k: int = 256) -> int:
 
     ``decode_attention_kernel`` falls back to a full-cache ``jnp.pad`` copy
     when ``S % block_k != 0`` (with block_k capped at S) — a whole-cache
-    read+write on EVERY decode step. Cache owners (serve KV pools, engines)
-    allocate ``round_kv_len(max_len)`` rows instead; the extra rows stay
-    masked by ``cur_len`` forever.
+    read+write on EVERY decode step. The engine's static path allocates
+    ``round_kv_len(max_len)`` rows instead; the extra rows stay masked by
+    ``cur_len`` forever.
     """
     if n <= block_k:
         return n
@@ -145,103 +138,6 @@ def decode_attention_kernel(q, k_cache, v_cache, cur_len, *, sm_scale=None,
         interpret=interpret,
         name="decode_attention",
     )(lens, qf, kf, vf)
-    return out.reshape(b, kvh * g, hd)
-
-
-# ---------------------------------------------------------------------------
-# paged flash-decode (block-table KV pool)
-# ---------------------------------------------------------------------------
-
-def _paged_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
-                  acc_scr, *, sm_scale, block_size, npages, kvh):
-    pi = pl.program_id(1)
-    cur_len = len_ref[pl.program_id(0) // kvh]
-
-    @pl.when(pi == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    # pages at/past the row's depth are unmapped (block table holds 0 there);
-    # skipping them means a request only ever streams its resident pages
-    @pl.when(pi * block_size < cur_len)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)                    # (g, hd)
-        k = k_ref[0, 0].astype(jnp.float32)                 # (bs, hd)
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        kpos = pi * block_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos < cur_len, s, NEG)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
-
-    @pl.when(pi == npages - 1)
-    def _finalize():
-        o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
-
-
-def paged_decode_attention_kernel(q, k_pages, v_pages, block_tables, cur_len,
-                                  *, sm_scale=None, interpret=False):
-    """Flash-decode over a paged KV pool.
-
-    q: (b, h, hd); k_pages/v_pages: (num_blocks, block_size, kvh, hd) —
-    the global page pool shared by every request; block_tables: (b, npages)
-    int32 — per-row physical page ids (unmapped entries hold 0 and are never
-    read past ``cur_len``); cur_len: (b,) int32 valid lengths.
-
-    ``cur_len`` and the block tables are scalar-prefetched: each row's
-    BlockSpec index_map dereferences its own table slice, so the kernel
-    streams exactly that row's resident pages — no gather materialization,
-    no recompilation as the pool mapping churns. Rows with ``cur_len <= 0``
-    produce zeros.
-    """
-    b, h, hd = q.shape
-    block_size, kvh = k_pages.shape[1], k_pages.shape[2]
-    npages = block_tables.shape[1]
-    g = h // kvh
-    scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(hd)
-
-    qf = q.reshape(b, kvh, g, hd).reshape(b * kvh, g, hd)
-    kf = k_pages.transpose(2, 0, 1, 3)          # (kvh, num_blocks, bs, hd)
-    vf = v_pages.transpose(2, 0, 1, 3)
-    lens = jnp.asarray(cur_len, jnp.int32)
-    bt = jnp.asarray(block_tables, jnp.int32)
-
-    kern = functools.partial(_paged_kernel, sm_scale=scale,
-                             block_size=block_size, npages=npages, kvh=kvh)
-    page_spec = pl.BlockSpec(
-        (1, 1, block_size, hd),
-        lambda bh, pi, lens, bt: (bh % kvh, bt[bh // kvh, pi], 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b * kvh, npages),
-        in_specs=[
-            pl.BlockSpec((1, g, hd), lambda bh, pi, lens, bt: (bh, 0, 0)),
-            page_spec,
-            page_spec,
-        ],
-        out_specs=pl.BlockSpec((1, g, hd), lambda bh, pi, lens, bt: (bh, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, hd), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b * kvh, g, hd), q.dtype),
-        interpret=interpret,
-        name="paged_decode_attention",
-    )(lens, bt, qf, kf, vf)
     return out.reshape(b, kvh * g, hd)
 
 

@@ -14,7 +14,6 @@ import jax.numpy as jnp
 from repro.kernels.aot_bias import (aot_gather_add_kernel,
                                     aot_gather_add_multitask_kernel)
 from repro.kernels.decode_attention import (decode_attention_kernel,
-                                            paged_decode_attention_kernel,
                                             ragged_paged_attention_kernel)
 from repro.kernels.flash_attention import flash_attention_kernel
 
@@ -46,14 +45,6 @@ def flash_attention(q, k, v, *, causal=True, window=0, prefix_len=0,
 def decode_attention(q, k_cache, v_cache, cur_len, *, block_k=256):
     return decode_attention_kernel(q, k_cache, v_cache, cur_len,
                                    block_k=block_k, interpret=_interpret())
-
-
-@jax.jit
-def paged_decode_attention(q, k_pages, v_pages, block_tables, cur_len):
-    """q: (b, h, hd); pages: (num_blocks, block_size, kvh, hd);
-    block_tables: (b, npages); cur_len: (b,). The serve-path paged decode."""
-    return paged_decode_attention_kernel(q, k_pages, v_pages, block_tables,
-                                         cur_len, interpret=_interpret())
 
 
 @jax.jit

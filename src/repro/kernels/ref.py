@@ -49,22 +49,6 @@ def decode_attention_ref(q, k_cache, v_cache, cur_len, *, sm_scale=None):
     return o.reshape(b, h, hd)
 
 
-def paged_decode_attention_ref(q, k_pages, v_pages, block_tables, cur_len,
-                               *, sm_scale=None):
-    """q: (b, h, hd); pages: (num_blocks, block_size, kvh, hd);
-    block_tables: (b, npages) int32; cur_len: (b,) int32.
-
-    Gathers each row's pages into a contiguous view and defers to the
-    contiguous decode oracle — the semantic contract: a paged cache is
-    just a scattered layout of the same KV rows.
-    """
-    b = q.shape[0]
-    bs, kvh, hd = k_pages.shape[1], k_pages.shape[2], k_pages.shape[3]
-    k = jnp.take(k_pages, block_tables, axis=0).reshape(b, -1, kvh, hd)
-    v = jnp.take(v_pages, block_tables, axis=0).reshape(b, -1, kvh, hd)
-    return decode_attention_ref(q, k, v, cur_len, sm_scale=sm_scale)
-
-
 def ragged_paged_attention_ref(q, k_pages, v_pages, block_tables, token_rows,
                                token_pos, *, sm_scale=None):
     """q: (T, h, hd) packed tokens; pages: (num_blocks, block_size, kvh, hd);

@@ -294,18 +294,15 @@ def main():
                           "keep serving (0 = off; requires --journal)")
     ap.add_argument("--slots", type=int, default=4,
                     help="KV-pool slots (continuous batch width)")
-    ap.add_argument("--layout", choices=("paged", "slots"), default="paged",
-                    help="KV pool layout: block-table pages (default) or "
-                         "one contiguous max-len region per slot")
     ap.add_argument("--block-size", type=int, default=16,
-                    help="KV page size in tokens (--layout paged)")
+                    help="KV page size in tokens")
     ap.add_argument("--num-blocks", type=int, default=0,
                     help="physical KV pages incl. the scratch page "
-                         "(0 = capacity parity with --layout slots)")
+                         "(0 = one --max-len of pages per slot)")
     ap.add_argument("--prefill-chunk", type=int, default=32,
-                    help="per-tick prefill token budget: prompts stream "
-                         "through the unified serve step in chunks drawn "
-                         "from it (0 = whole-prompt prefill)")
+                    help="per-tick prefill token budget (>= 1): prompts "
+                         "stream through the unified serve step in chunks "
+                         "drawn from it")
     ap.add_argument("--max-prefills", type=int, default=4,
                     help="prompts allowed to chunk concurrently, splitting "
                          "the per-tick budget shortest-remaining-first "
@@ -316,8 +313,7 @@ def main():
                          "finished requests' full prompt pages are retained "
                          "(LRU) and matched into later same-task "
                          "admissions, so their prefill starts at the first "
-                         "uncached token (needs --layout paged and "
-                         "--prefill-chunk > 0; 0 = off)")
+                         "uncached token (0 = off)")
     pc.add_argument("--system-prompt", type=int, default=0,
                     help="repeated-system-prompt workload: prepend a fixed "
                          "per-task system prefix of this many tokens to "
@@ -334,7 +330,7 @@ def main():
                       help="nucleus sampling mass (1.0 = off)")
     samp.add_argument("--samples", type=int, default=1,
                       help="parallel samples per request (n > 1 shares the "
-                           "prefill KV pages copy-on-write; --layout paged)")
+                           "prefill KV pages copy-on-write)")
     samp.add_argument("--seed", type=int, default=0,
                       help="base RNG seed (request i uses seed + i)")
     ap.add_argument("--prompt", type=int, default=16,
@@ -380,15 +376,9 @@ def main():
                  f"{args.prompt} + --steps {args.steps} cannot fit "
                  f"--max-len {args.max_len}; raise --max-len or shrink the "
                  "requests")
-    if args.prefix_cache_pages > 0 and (args.layout != "paged"
-                                        or args.prefill_chunk <= 0):
-        ap.error(f"--prefix-cache-pages {args.prefix_cache_pages} needs "
-                 "--layout paged with --prefill-chunk > 0 (cached pages "
-                 "are mapped through block tables and prefill resumes at "
-                 "the first uncached token)")
-    if args.samples > 1 and args.layout != "paged":
-        ap.error(f"--samples {args.samples} needs --layout paged "
-                 "(parallel samples share prefill KV via COW page forking)")
+    if args.prefill_chunk < 1:
+        ap.error(f"--prefill-chunk {args.prefill_chunk}: the per-tick "
+                 "prefill budget must be at least 1 token")
     if args.crash_at_tick > 0 and not args.journal:
         ap.error("--crash-at-tick needs --journal (recovery replays the "
                  "journal; without one there is nothing to restore from)")
@@ -496,10 +486,6 @@ def main():
             else dataclasses.replace(sampling, seed=args.seed + i))
         arrivals.append((ticks[i], req))
 
-    if args.prefill_chunk > 0 and args.layout != "paged":
-        print("warning: chunked prefill rides the unified paged serve step; "
-              "--layout slots falls back to whole-prompt prefills")
-        args.prefill_chunk = 0
     want_obs = (args.metrics or args.metrics_out or args.trace_out
                 or args.jax_profile or args.check_leaks)
     obs = None
@@ -509,8 +495,8 @@ def main():
             trace=bool(args.trace_out), jax_profile_dir=args.jax_profile,
             check_leaks=args.check_leaks)
     sched_cfg = SchedulerConfig(
-        num_slots=args.slots, kv_layout=args.layout,
-        block_size=args.block_size, num_blocks=args.num_blocks,
+        num_slots=args.slots, block_size=args.block_size,
+        num_blocks=args.num_blocks,
         prefill_chunk=args.prefill_chunk, max_prefills=args.max_prefills,
         prefix_cache_pages=args.prefix_cache_pages,
         max_queue=args.max_queue)
@@ -545,9 +531,8 @@ def main():
                 print(f"tick trace -> {args.trace_out} "
                       f"({len(obs.tracer.events)} events; load in "
                       "chrome://tracing or ui.perfetto.dev)")
-    # a tick is not "one decode step plus maybe one prefill chunk" anymore:
-    # the paged path folds chunk + decode rows into ONE device call, so
-    # report realized dispatches per tick instead of assuming the split.
+    # a tick folds chunk + decode rows into ONE device call, so report
+    # realized dispatches per tick instead of assuming a split.
     # sched.ticks counts REAL step() calls only; sched.clock additionally
     # fast-forwards across idle gaps in the arrival stream, so the
     # difference is exactly the idle air that must never leak into
@@ -560,25 +545,24 @@ def main():
           f"{sched.steps_decoded} decode steps, {sched.prefill_chunks_run} "
           f"prefill chunks, {sched.tokens_emitted} tokens, "
           f"{eng.dispatches} device dispatches ({per_tick:.2f}/tick, "
-          f"{args.slots} slots, layout={args.layout})")
-    if sched.paged:
-        pool = sched.pool
-        print(f"paged pool: {pool.num_blocks - 1} usable pages x "
-              f"{pool.block_size} tokens, peak pages {pool.peak_pages}, "
-              f"peak concurrency {sched.peak_running}, "
-              f"peak concurrent prefills {sched.peak_prefills}, "
-              f"{sched.preemptions} preemptions, "
-              f"{pool.forks} forks, {pool.cow_copies} COW page copies")
-        cache = pool.prefix_cache
-        if cache is not None:
-            total = cache.hits + cache.misses
-            rate = cache.hits / max(total, 1)
-            print(f"prefix cache ({cache.capacity} pages): {cache.hits}/"
-                  f"{total} admissions hit ({rate:.0%}), "
-                  f"{cache.hit_tokens} prefill tokens skipped, "
-                  f"{cache.retained_pages} pages retained, "
-                  f"{cache.evicted_pages} evicted, {len(cache)} resident "
-                  "at exit")
+          f"{args.slots} slots)")
+    pool = sched.pool
+    print(f"paged pool: {pool.num_blocks - 1} usable pages x "
+          f"{pool.block_size} tokens, peak pages {pool.peak_pages}, "
+          f"peak concurrency {sched.peak_running}, "
+          f"peak concurrent prefills {sched.peak_prefills}, "
+          f"{sched.preemptions} preemptions, "
+          f"{pool.forks} forks, {pool.cow_copies} COW page copies")
+    cache = pool.prefix_cache
+    if cache is not None:
+        total = cache.hits + cache.misses
+        rate = cache.hits / max(total, 1)
+        print(f"prefix cache ({cache.capacity} pages): {cache.hits}/"
+              f"{total} admissions hit ({rate:.0%}), "
+              f"{cache.hit_tokens} prefill tokens skipped, "
+              f"{cache.retained_pages} pages retained, "
+              f"{cache.evicted_pages} evicted, {len(cache)} resident "
+              "at exit")
     if retries or shed_rids or sched.shed or sched.aborted:
         print(f"overload: {retries} shed retries (backoff base "
               f"{args.backoff}), {len(shed_rids)} requests gave up after "

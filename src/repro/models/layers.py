@@ -326,25 +326,6 @@ def attention_decode(q, k_cache, v_cache, cur_len, *, window=0, softcap=0.0):
     return out.reshape(b, 1, h, hd)
 
 
-def paged_attention_decode(q, k_pages, v_pages, block_tables, cur_len, *,
-                           softcap=0.0):
-    """Single-token decode attention against a paged KV pool (XLA path).
-
-    q: (b, 1, H, hd); k_pages/v_pages: (num_blocks, block_size, KV, hd);
-    block_tables: (b, npages) int32 physical page ids (unmapped entries are
-    0 — their rows sit past ``cur_len`` and are masked); cur_len: (b,) int32.
-
-    Gathers each row's pages into a contiguous (b, npages*bs) view and
-    reuses ``attention_decode``; the Pallas kernel path streams pages
-    directly without materializing the gather.
-    """
-    b = q.shape[0]
-    bs, kvh, hd = k_pages.shape[1], k_pages.shape[2], k_pages.shape[3]
-    k = jnp.take(k_pages, block_tables, axis=0).reshape(b, -1, kvh, hd)
-    v = jnp.take(v_pages, block_tables, axis=0).reshape(b, -1, kvh, hd)
-    return attention_decode(q, k, v, cur_len, softcap=softcap)
-
-
 def ragged_paged_attention_decode(q, k_pages, v_pages, block_tables,
                                   token_rows, token_pos, *, softcap=0.0):
     """Packed ragged mixed-batch attention against a paged KV pool (XLA).
@@ -358,9 +339,9 @@ def ragged_paged_attention_decode(q, k_pages, v_pages, block_tables,
 
     Gathers each token's slot pages contiguous and defers to
     :func:`attention_decode` with per-token valid length ``token_pos + 1``
-    — element-for-element the :func:`paged_attention_decode` computation,
-    so greedy decode parity carries over bitwise. Dead tokens output zeros
-    (matching the Pallas ragged kernel).
+    — element-for-element the contiguous decode computation over the same
+    KV rows. Dead tokens output zeros (matching the Pallas ragged
+    kernel).
     """
     T = q.shape[0]
     bs, kvh, hd = k_pages.shape[1], k_pages.shape[2], k_pages.shape[3]

@@ -321,7 +321,7 @@ class Model:
         softcap = cfg.logit_softcap
         new_cache = cache_u
 
-        if cache_u is not None and token_rows is not None and block_tables is not None:
+        if token_rows is not None:
             # ---- unified ragged mixed step: the batch axis is a PACKED
             # token list (decode rows one token each, every in-flight
             # prefill its chunk, zero padding compute). decode_pos carries
@@ -331,8 +331,8 @@ class Model:
             # that slot's resident pages ----
             if window:
                 raise NotImplementedError(
-                    "paged serving has no sliding-window masking; serve SWA "
-                    "models with the contiguous slot layout")
+                    "paged serving has no sliding-window masking yet; serve "
+                    "SWA models statically with ServeEngine.generate")
             bs_page = cache_u["k"].shape[1]
             live = decode_pos >= 0
             pos = jnp.maximum(decode_pos, 0)
@@ -350,29 +350,6 @@ class Model:
                 o = L.ragged_paged_attention_decode(q, kc, vc, block_tables,
                                                     token_rows, decode_pos,
                                                     softcap=softcap)
-            new_cache = {"k": kc, "v": vc}
-        elif cache_u is not None and decode_pos is not None and block_tables is not None:
-            # ---- paged decode: cache leaves are the global page pool
-            # (num_blocks, block_size, kvh, hd); each row's new KV lands in
-            # the page its block table maps for depth decode_pos ----
-            if window:
-                raise NotImplementedError(
-                    "paged decode has no sliding-window masking; serve SWA "
-                    "models with the contiguous slot layout")
-            bs_page = cache_u["k"].shape[1]
-            rows = jnp.arange(b)
-            page = block_tables[rows, decode_pos // bs_page]
-            off = decode_pos % bs_page
-            kc = cache_u["k"].at[page, off].set(k[:, 0].astype(cache_u["k"].dtype))
-            vc = cache_u["v"].at[page, off].set(v[:, 0].astype(cache_u["v"].dtype))
-            valid = decode_pos + 1
-            if opts.attn_impl == "pallas" and not softcap:
-                from repro.kernels import ops as kops
-                o = kops.paged_decode_attention(q[:, 0], kc, vc, block_tables,
-                                                valid)[:, None]
-            else:
-                o = L.paged_attention_decode(q, kc, vc, block_tables, valid,
-                                             softcap=softcap)
             new_cache = {"k": kc, "v": vc}
         elif cache_u is not None and decode_pos is not None:
             # ---- decode: write new kv, attend over cache ----
@@ -751,36 +728,19 @@ class Model:
         return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
                             self.paged_cache_specs(num_blocks, block_size))
 
-    def _group_cache_view(self, cache, gi, plan):
-        """Per-group cache dict keyed b0.. -> stacked (R, U is dict) for scan."""
-        g = cache[gi]
-        # scan xs need leaves (R, ...) with unit positions as a dict level.
-        return {k: v for k, v in g.items()}
-
-    def prefill(self, params, batch, peft=None, *, max_len: int, last_pos=None):
-        """Run the prompt, build the cache. Returns (last_logits, cache, pos).
-
-        ``last_pos`` (traced scalar) selects which position's logits to
-        return instead of the final one — used by the continuous scheduler,
-        whose prompts are right-padded to a bucket length (causality makes
-        positions <= last_pos independent of the padding)."""
-        self.decode_max_len = max_len
+    def prefill(self, params, batch, peft=None, *, max_len: int):
+        """Run the prompt, build the cache. Returns (last_logits, cache, pos)."""
         cache = self.init_cache(_batch_size(batch), max_len)
         h, ids, e_rows, positions, prompt_len = self._embed(params, batch, peft)
         new_cache = []
         for gi, plan in enumerate(self.plan):
-            gcache = _unitdict_to_xs(cache[gi])
             h, _, gc = self._group_apply(
                 params["groups"][gi], plan, h, ids=ids, e_rows=e_rows,
-                positions=positions, peft=peft, rng=None, gcache=gcache,
+                positions=positions, peft=peft, rng=None, gcache=cache[gi],
                 decode_pos=None, prompt_len=prompt_len)
-            new_cache.append(_xs_to_unitdict(gc))
+            new_cache.append(gc)
         h = L.apply_norm(self.cfg, params["final_norm"], h)
-        if last_pos is None:
-            h_last = h[:, -1:]
-        else:
-            h_last = jax.lax.dynamic_slice_in_dim(h, last_pos, 1, axis=1)
-        logits = self.unembed(params, h_last)
+        logits = self.unembed(params, h[:, -1:])
         n = batch_len(batch)
         if peft and peft["method"] == "ptv2":   # prefix kv occupies cache slots
             n += peft["opt"].prompt_len
@@ -788,16 +748,12 @@ class Model:
         return logits, new_cache, pos
 
     def decode_step(self, params, tokens, pos, cache, peft=None,
-                    rope_pos=None, extra: Optional[dict] = None,
-                    block_tables=None):
-        """One decode step. tokens: (b, 1); pos: scalar int32 — cache slot of
-        the new token — or a per-row (b,) vector when every row sits at its
-        own depth (continuous batching over a slotted KV pool). ``rope_pos``
-        overrides the positional index when they differ, e.g. ptv2 prefixes
-        occupy cache slots but not rope positions. ``block_tables`` (b,
-        npages) switches the attention caches to paged-pool layout
-        (``init_paged_cache``): each row's KV scatter and attention route
-        through its block-table slice; ``pos`` must then be per-row.
+                    rope_pos=None, extra: Optional[dict] = None):
+        """One decode step over a contiguous cache (``init_cache``).
+        tokens: (b, 1); pos: scalar int32 — cache slot of the new token —
+        or a per-row (b,) vector when every row sits at its own depth.
+        ``rope_pos`` overrides the positional index when they differ, e.g.
+        ptv2 prefixes occupy cache slots but not rope positions.
         Returns (logits (b,1,V), new_cache)."""
         cfg = self.cfg
         dt = self.opts.compute_dtype
@@ -822,12 +778,11 @@ class Model:
             h = h + (pe if pe.ndim == 3 else pe[None])
         new_cache = []
         for gi, plan in enumerate(self.plan):
-            gcache = _unitdict_to_xs(cache[gi])
             h, _, gc = self._group_apply(
                 params["groups"][gi], plan, h, ids=ids, e_rows=e_rows,
-                positions=positions, peft=peft, rng=None, gcache=gcache,
-                decode_pos=pos, prompt_len=0, block_tables=block_tables)
-            new_cache.append(_xs_to_unitdict(gc))
+                positions=positions, peft=peft, rng=None, gcache=cache[gi],
+                decode_pos=pos, prompt_len=0)
+            new_cache.append(gc)
         h = L.apply_norm(cfg, params["final_norm"], h)
         return self.unembed(params, h), new_cache
 
@@ -875,13 +830,12 @@ class Model:
             h = h + jnp.take(params["embed"]["pos"], positions, axis=0).astype(dt)
         new_cache = []
         for gi, plan in enumerate(self.plan):
-            gcache = _unitdict_to_xs(cache[gi])
             h, _, gc = self._group_apply(
                 params["groups"][gi], plan, h, ids=ids, e_rows=e_rows,
-                positions=positions, peft=peft, rng=None, gcache=gcache,
+                positions=positions, peft=peft, rng=None, gcache=cache[gi],
                 decode_pos=token_pos, prompt_len=0,
                 block_tables=block_tables, token_rows=token_rows)
-            new_cache.append(_xs_to_unitdict(gc))
+            new_cache.append(gc)
         with jax.named_scope("logits"):
             h = L.apply_norm(cfg, params["final_norm"], h)
             if logit_idx is None:
@@ -898,14 +852,6 @@ class Model:
 def _stack_unit(dicts):
     """[{...}, {...}] per unit position -> {"b0": ..., "b1": ...} for ys."""
     return {f"b{u}": d for u, d in enumerate(dicts)}
-
-
-def _unitdict_to_xs(g):
-    return g
-
-
-def _xs_to_unitdict(g):
-    return g
 
 
 def _batch_size(batch) -> int:

@@ -6,20 +6,19 @@ are indexed per (task, token) during both prefill and decode, at gather+add
 cost. No extra sequence length (vs P-Tuning), no extra matmuls (vs
 LoRA-unfused/Adapters) — the zero-cost property of Table 1.
 
-Two serving modes share the same jitted model functions:
+Two serving modes share the same model:
 
-  * ``generate``: static batch — every request arrives together, shares one
-    prompt length, finishes together (the paper's benchmark setting).
-  * the continuous path, driven by :mod:`repro.serve.scheduler`. For the
-    paged KV pool the whole tick is ONE jitted :meth:`serve_step` call — a
-    ragged PACKED token list where each decode row contributes one token
+  * ``generate``: static batch over a contiguous cache — every request
+    arrives together, shares one prompt length, finishes together (the
+    paper's benchmark setting).
+  * :meth:`ServeEngine.serve_step`, the continuous path driven by
+    :mod:`repro.serve.scheduler`: a whole tick is ONE jitted call over a
+    ragged PACKED token list, where each decode row contributes one token
     and every in-flight prefill its next chunk (several prompts chunk
-    concurrently, every token tagged with its owning slot and position),
-    each token's KV scatters straight into
-    its slot's block-table-mapped pool pages, and per-slot sampling
-    vectors fold the token draw into the same dispatch. The
-    contiguous :class:`repro.serve.kv_pool.SlotKVPool` comparison layout
-    keeps the older ``prefill_request`` + ``decode_mixed`` pair. Because
+    concurrently, every token tagged with its owning slot and position).
+    Each token's KV scatters straight into its slot's block-table-mapped
+    pages of the :class:`repro.serve.kv_pool.PagedKVPool`, and per-slot
+    sampling vectors fold the token draw into the same dispatch. Because
     the AoT bias is a per-(task, token) gather, a mixed-task batch costs
     exactly what a single-task batch costs.
 
@@ -30,7 +29,7 @@ bitfit, and plain backbone.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Optional, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -93,8 +92,6 @@ class ServeEngine:
         self.cache_len = round_kv_len(cfg.max_len)
         self._decode = jax.jit(self._decode_impl)
         self._prefill = jax.jit(self._prefill_impl)
-        self._prefill_at = jax.jit(self._prefill_at_impl)
-        self._decode_sampled = jax.jit(self._decode_sampled_impl)
         self._sample_row = jax.jit(self._sample_row_impl)
         # the unified ragged prefill+decode step: two traces (greedy batches
         # keep the exact-argmax path), each still ONE dispatch per tick.
@@ -124,14 +121,12 @@ class ServeEngine:
     def attach_metrics(self, registry) -> None:
         """Per-kind dispatch counters on an obs registry. Incremented on
         the host around the jitted calls, never inside them — a tick's
-        dispatch anatomy (serve_step vs legacy prefill+decode pairs vs
-        n>1 first-token draws, and the watchdog's finite_rows) becomes
-        visible without touching traces."""
+        dispatch anatomy (serve_step, n>1 first-token draws, and the
+        watchdog's finite_rows) becomes visible without touching traces."""
         self._m = {kind: registry.counter(
             f"engine_dispatch_{kind}_total",
             f"device dispatches via {kind}")
-            for kind in ("serve_step", "prefill", "decode_mixed",
-                         "sample_first", "finite_rows")}
+            for kind in ("serve_step", "sample_first", "finite_rows")}
 
     def attach_tracer(self, tracer) -> None:
         """Record the engine's host phases (``engine.inputs``,
@@ -175,25 +170,9 @@ class ServeEngine:
         peft = self._peft_for(pp, task_ids)
         return self.model.prefill(params, batch, peft, max_len=self.cache_len)
 
-    def _prefill_at_impl(self, params, pp, tokens, last_pos, task_ids):
-        """Bucket prefill: logits taken at ``last_pos`` (last real token)."""
-        peft = self._peft_for(pp, task_ids)
-        return self.model.prefill(params, {"tokens": tokens}, peft,
-                                  max_len=self.cache_len, last_pos=last_pos)
-
     def _decode_impl(self, params, pp, tokens, pos, cache, task_ids):
         peft = self._peft_for(pp, task_ids)
         return self.model.decode_step(params, tokens, pos, cache, peft)
-
-    # sampled variant: the decode step and the per-slot token draw fuse
-    # into one jitted pass (temperature 0 rows reduce to exact argmax)
-    def _decode_sampled_impl(self, params, pp, tokens, pos, cache, task_ids,
-                             temps, top_ks, top_ps, base_keys, steps):
-        logits, cache = self._decode_impl(params, pp, tokens, pos, cache,
-                                          task_ids)
-        toks = sample_tokens(logits[:, -1], temps, top_ks, top_ps,
-                             base_keys, steps)
-        return toks, cache
 
     def _serve_step_impl(self, params, pp, tokens, token_rows, token_pos,
                          logit_idx, cache, token_tasks, block_tables, temps,
@@ -275,29 +254,6 @@ class ServeEngine:
                 jnp.asarray(base_keys, jnp.uint32),
                 jnp.asarray(steps, jnp.int32))
 
-    def prefill_request(self, tokens: np.ndarray, length: int, task_id: int,
-                        sample=None) -> Tuple[list, Any]:
-        """Prefill one bucket-padded prompt. tokens: (1, bucket) int32;
-        ``length``: real prompt tokens. Returns (first tokens, cache) —
-        a single greedy token when ``sample`` is None, else one draw per
-        parallel sample from the spec's (n,)-shaped vectors (the n-samples
-        path: every sample shares this one prefill).
-
-        One compilation per distinct bucket length; padding is inert under
-        causal attention, so logits at ``length - 1`` and KV rows
-        ``[0, length)`` match an unpadded prefill bitwise."""
-        tids = jnp.full((1,), task_id, jnp.int32)
-        logits, cache, _ = self._prefill_at(
-            self.params, self._pp, jnp.asarray(tokens),
-            jnp.asarray(length - 1, jnp.int32), tids)
-        self._count("prefill")
-        return self._first_tokens(logits, sample), cache
-
-    def _first_tokens(self, logits, sample) -> list:
-        if sample is None:
-            return [int(jax.device_get(jnp.argmax(logits[0, -1])))]
-        return self.sample_first(logits[0, -1], sample)
-
     def sample_first(self, logits_row, sample) -> list:
         """Draw the spec's first tokens from ONE logits row — the n>1
         parallel-samples path, where every sample's token 0 comes from the
@@ -310,31 +266,6 @@ class ServeEngine:
             self._count("sample_first")
         with tr.span("engine.outputs"):
             return [int(t) for t in np.asarray(jax.device_get(toks))]
-
-    def decode_mixed(self, tokens: np.ndarray, pos: np.ndarray, cache,
-                     task_ids: np.ndarray, sample=None):
-        """One mixed step over all pool slots.
-
-        tokens: (num_slots, 1) last token per slot; pos: (num_slots,) per-slot
-        depths (== cur_len; the new KV row is written there); task_ids:
-        (num_slots,). Free slots ride along with pos=0 and are ignored by the
-        caller. ``sample``: optional per-slot (temps, top_ks, top_ps,
-        base_keys, steps) spec — None keeps the pure-greedy fast path.
-        Returns (next token per slot (num_slots,), new cache)."""
-        self._count("decode_mixed")
-        if sample is None:
-            logits, cache = self._decode(
-                self.params, self._pp, jnp.asarray(tokens),
-                jnp.asarray(pos, np.int32), cache,
-                jnp.asarray(task_ids, np.int32))
-            toks = np.asarray(jax.device_get(
-                jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)))
-            return toks, cache
-        toks, cache = self._decode_sampled(
-            self.params, self._pp, jnp.asarray(tokens),
-            jnp.asarray(pos, np.int32), cache, jnp.asarray(task_ids, np.int32),
-            *self._sample_vecs(sample))
-        return np.asarray(jax.device_get(toks)), cache
 
     def serve_step(self, tokens: np.ndarray, token_rows: np.ndarray,
                    token_pos: np.ndarray, logit_idx: np.ndarray, cache,

@@ -199,8 +199,6 @@ class FaultInjector:
         self._held = still
         for ev in self._by_tick.get(t, ()):
             if ev.kind == "exhaust":
-                if not hasattr(sched.pool, "seize_pages"):
-                    continue        # slots layout: no page pool to squeeze
                 pages = sched.pool.seize_pages(ev.pages)
                 if pages:
                     self._held.append((t + ev.dur, pages))

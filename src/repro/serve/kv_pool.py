@@ -1,39 +1,33 @@
-"""KV pools for continuous batching: contiguous slots and paged blocks.
+"""The paged KV pool for continuous batching: pages, block tables, sharing.
 
-``SlotKVPool`` owns one model cache pytree sized ``(num_slots, max_len)`` —
-every leaf keeps the slot (batch) axis at position 1, after the per-layer
-repeats axis — plus per-slot ``cur_len`` / ``task_id`` host arrays and a
-free list. Admitting a request allocates a slot and copies the request's
-prefilled cache into it in place (``dynamic_update_slice`` on a traced slot
-index, so batch composition changes never recompile); decode appends happen
-inside the engine's mixed step, which scatters each slot's new KV row at
-that slot's own depth.
+``PagedKVPool`` holds a global pool of ``block_size``-token KV pages plus
+per-slot block tables: HBM is claimed page-by-page as requests actually
+deepen, so capacity is bounded by *tokens in flight*, not ``num_slots *
+max_len``. Page 0 is a reserved scratch page — dead padding tokens of the
+serve step scatter their KV there, and unmapped block-table entries point
+at it (they are only ever read past a slot's depth, i.e. fully masked).
+The serve step writes every token's KV straight into its slot's pages;
+the pool only publishes depths (:meth:`PagedKVPool.commit_prefill`,
+:meth:`PagedKVPool.advance`).
 
-``PagedKVPool`` replaces the one-contiguous-region-per-slot layout with a
-global pool of ``block_size``-token KV pages plus per-slot block tables:
-HBM is claimed page-by-page as requests actually deepen, so capacity is
-bounded by *tokens in flight*, not ``num_slots * max_len``. Page 0 is a
-reserved scratch page — free slots riding along in the mixed decode step
-scatter their garbage KV row there, and unmapped block-table entries point
-at it (they are only ever read past ``cur_len``, i.e. fully masked).
+Pages are refcounted so slots can share them: copy-on-write forks (n > 1
+samples), the cross-request :class:`PrefixCache`, and compaction of
+duplicate prompt pages.
 
 Bookkeeping (alloc/free, lengths, task ids, block tables) is deliberately
 host-side numpy: it is O(num_slots + num_blocks) integers, mutated between
-device steps, and the decode step only consumes it as small int vectors.
+device steps, and the serve step only consumes it as small int vectors.
 """
 from __future__ import annotations
 
 import hashlib
 from collections import OrderedDict
 from dataclasses import dataclass, field
-from functools import partial
 from typing import Any, Dict, List, Optional, Sequence, Set
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from repro.kernels.decode_attention import round_kv_len
 
 
 def chain_keys(task_id: int, toks, block_size: int,
@@ -54,173 +48,6 @@ def chain_keys(task_id: int, toks, block_size: int,
         prev = hashlib.blake2b(prev + block, digest_size=16).digest()
         keys.append(prev)
     return keys
-
-
-def _write_slot_impl(pool_cache, req_cache, slot):
-    """Copy a batch=1 prefill cache into ``slot`` of the pool cache.
-
-    Leaves are (repeats, batch, ...); the update writes at offset 0 on every
-    axis except the slot axis, so a prefill cache with a shorter sequence
-    axis (chunked prefill) lands at the front of the slot's KV rows.
-    """
-    def wr(p, c):
-        start = (0, slot) + (0,) * (p.ndim - 2)
-        return jax.lax.dynamic_update_slice(p, c.astype(p.dtype), start)
-    return jax.tree.map(wr, pool_cache, req_cache)
-
-
-_WRITE_SLOT = None
-
-
-def _write_slot(pool_cache, req_cache, slot):
-    global _WRITE_SLOT
-    if _WRITE_SLOT is None:
-        # donate the pool buffers so the in-place write never doubles HBM;
-        # CPU (tests) has no donation support, so skip it there
-        donate = (0,) if jax.default_backend() == "tpu" else ()
-        _WRITE_SLOT = jax.jit(_write_slot_impl, donate_argnums=donate)
-    return _WRITE_SLOT(pool_cache, req_cache, slot)
-
-
-class SlotKVPool:
-    """Fixed-capacity slotted decode cache shared by all in-flight requests."""
-
-    def __init__(self, model, num_slots: int, max_len: int):
-        self.model = model
-        self.num_slots = num_slots
-        self.max_len = max_len
-        # rounded so the Pallas decode kernel never pads (rows past max_len
-        # stay masked by cur_len forever)
-        self.alloc_len = round_kv_len(max_len)
-        self.cache = model.init_cache(num_slots, self.alloc_len)
-        self.cur_len = np.zeros(num_slots, np.int32)
-        self.task_id = np.zeros(num_slots, np.int32)
-        self._free: List[int] = list(range(num_slots - 1, -1, -1))
-        self._used: Set[int] = set()
-        self._m = None                      # optional obs instruments
-
-    def attach_metrics(self, registry) -> None:
-        """Slot-occupancy gauge (the contiguous layout has no pages)."""
-        self._m = {"slots_used": registry.gauge(
-            "kv_slots_used", "occupied decode slots")}
-        self._gauge_sync()
-
-    def _gauge_sync(self) -> None:
-        if self._m is not None:
-            self._m["slots_used"].set(len(self._used))
-
-    # ------------------------------------------------------------------
-    # slot lifecycle
-    # ------------------------------------------------------------------
-    def has_free(self) -> bool:
-        return bool(self._free)
-
-    def num_free(self) -> int:
-        return len(self._free)
-
-    def occupied(self) -> List[int]:
-        return sorted(self._used)
-
-    def alloc(self, task_id: int = 0) -> Optional[int]:
-        """Claim a slot (None when full). cur_len starts at 0 until prefill."""
-        if not self._free:
-            return None
-        slot = self._free.pop()
-        self._used.add(slot)
-        self.task_id[slot] = task_id
-        self.cur_len[slot] = 0
-        self._gauge_sync()
-        return slot
-
-    def free(self, slot: int) -> None:
-        if slot not in self._used:
-            raise ValueError(f"slot {slot} is not allocated")
-        self._used.remove(slot)
-        self.cur_len[slot] = 0
-        self.task_id[slot] = 0
-        self._free.append(slot)
-        self._gauge_sync()
-
-    # ------------------------------------------------------------------
-    # cache writes
-    # ------------------------------------------------------------------
-    def write_prefill(self, slot: int, req_cache: Any, length: int) -> None:
-        """Install a request's prefilled cache into its slot.
-
-        ``length`` is the number of *real* prompt tokens; KV rows past it
-        (bucket padding) stay masked by ``cur_len`` until decode overwrites
-        them."""
-        if length > self.max_len:
-            raise ValueError(f"prompt length {length} exceeds pool max_len "
-                             f"{self.max_len}")
-        self.cache = _write_slot(self.cache, req_cache, slot)
-        self.cur_len[slot] = length
-
-    def advance(self, slots) -> None:
-        """Record one decode append for each slot in ``slots``."""
-        for s in slots:
-            self.cur_len[s] += 1
-
-    # ------------------------------------------------------------------
-    def leak_report(self) -> List[str]:
-        """Invariant sweep: every slot exactly one of free/used. Returns
-        human-readable findings (empty = clean) instead of asserting, so
-        the scheduler's drain-time debug check can *report* leaks through
-        the metrics snapshot in live runs; tests assert via
-        :meth:`check_no_leaks`."""
-        bad: List[str] = []
-        free = set(self._free)
-        if len(self._free) != len(free):
-            bad.append("duplicate slots on free list")
-        both = free & self._used
-        if both:
-            bad.append(f"slots both free and used: {sorted(both)}")
-        lost = set(range(self.num_slots)) - (free | self._used)
-        if lost:
-            bad.append(f"lost slots (neither free nor used): {sorted(lost)}")
-        deep = [s for s in free if self.cur_len[s] != 0]
-        if deep:
-            bad.append(f"freed slots with nonzero length: {deep}")
-        return bad
-
-    def check_no_leaks(self) -> None:
-        report = self.leak_report()
-        assert not report, "slot pool invariants violated: " + "; ".join(report)
-
-
-# ---------------------------------------------------------------------------
-# paged pool
-# ---------------------------------------------------------------------------
-
-@partial(jax.jit, static_argnums=1)
-def _pad_seq(req_cache, pad):
-    def pd(c):     # (repeats, 1, S, kvh, hd) -> S + pad
-        return jnp.pad(c, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-    return jax.tree.map(pd, req_cache)
-
-
-def _write_pages_impl(pool_cache, req_cache, pages):
-    """Scatter a batch=1 prefill cache into physical pages ``pages`` of
-    every layer's pool in ONE functional update. ``pages`` is a traced
-    (npages,) page-id vector, so page-table churn never recompiles; one
-    compilation per (npages, prefill length) combination — both bucketed."""
-    def wr(p, c):
-        bs = p.shape[2]      # p: (repeats, num_blocks, bs, kvh, hd)
-        n = pages.shape[0]
-        chunks = c[:, 0, :n * bs].reshape((c.shape[0], n, bs) + c.shape[3:])
-        return p.at[:, pages].set(chunks.astype(p.dtype))
-    return jax.tree.map(wr, pool_cache, req_cache)
-
-
-_WRITE_PAGES = None
-
-
-def _write_pages(pool_cache, req_cache, pages):
-    global _WRITE_PAGES
-    if _WRITE_PAGES is None:
-        donate = (0,) if jax.default_backend() == "tpu" else ()
-        _WRITE_PAGES = jax.jit(_write_pages_impl, donate_argnums=donate)
-    return _WRITE_PAGES(pool_cache, req_cache, jnp.asarray(pages, jnp.int32))
 
 
 def _copy_page_impl(pool_cache, src, dst):
@@ -545,7 +372,7 @@ class PagedKVPool:
         self.max_len = max_len
         self.block_size = block_size
         self.max_pages = -(-max_len // block_size)
-        if num_blocks is None:      # capacity parity with a contiguous pool
+        if num_blocks is None:      # one max_len of pages per slot
             num_blocks = num_slots * self.max_pages + 1
         assert num_blocks >= self.max_pages + 1, (
             f"num_blocks {num_blocks} cannot hold even one max_len request "
@@ -976,25 +803,6 @@ class PagedKVPool:
     # ------------------------------------------------------------------
     # cache writes
     # ------------------------------------------------------------------
-    def write_prefill(self, slot: int, req_cache: Any, length: int) -> None:
-        """Scatter a request's prefilled contiguous cache into its mapped
-        pages. ``length`` is the number of real prompt tokens; the slot must
-        already hold ``pages_needed(length)`` pages (admission allocates
-        them)."""
-        if length > self.max_len:
-            raise ValueError(f"prompt length {length} exceeds pool max_len "
-                             f"{self.max_len}")
-        npages = self.pages_needed(length)
-        pages = self._pages[slot]
-        assert len(pages) >= npages, (
-            f"slot {slot}: {len(pages)} pages mapped, prefill needs {npages}")
-        S = jax.tree.leaves(req_cache)[0].shape[2]
-        need = npages * self.block_size
-        if S < need:    # tail page extends past the prefill bucket: pad once
-            req_cache = _pad_seq(req_cache, need - S)
-        self.cache = _write_pages(self.cache, req_cache, pages[:npages])
-        self.cur_len[slot] = length
-
     def commit_prefill(self, slot: int, length: int) -> None:
         """Publish a prefill whose KV the unified serve step already
         scattered straight into this slot's mapped pages — bookkeeping

@@ -49,7 +49,7 @@ class SamplingParams:
     top_p: nucleus sampling — keep the smallest descending-probability
         prefix with cumulative mass >= top_p (1.0 = off).
     n: parallel samples per prompt. The scheduler prefills once and forks
-        the request's KV pages copy-on-write (paged layout), so n > 1 costs
+        the request's KV pages copy-on-write, so n > 1 costs
         one prefill and only the divergent decode pages.
     seed: root of the request's counter-based RNG stream.
     max_tokens: overrides Request.max_new_tokens when set.

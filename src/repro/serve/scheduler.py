@@ -2,43 +2,30 @@
 
 Static batching forces every request to arrive together, share one prompt
 length, and finish together. This scheduler serves realistic traffic: each
-request carries its own ``task_id``, prompt, and ``max_new_tokens``; new
-requests are admitted *between* decode steps, and every decode step is ONE
-jitted mixed pass over all occupied slots with per-slot positions and the
-multitask AoT gather routed by the slot task-id vector.
+request carries its own ``task_id``, prompt, and ``max_new_tokens``, and
+new requests are admitted between ticks.
 
-Two KV layouts share the same request lifecycle:
-
-  * ``kv_layout="paged"`` (default): a :class:`PagedKVPool` — KV pages are
-    claimed block-by-block as requests deepen, so HBM is bounded by tokens
-    in flight and ``num_slots`` can far exceed what ``num_slots * max_len``
-    contiguous regions would cost. A tick is ONE jitted
-    ``ServeEngine.serve_step`` call over a RAGGED, PACKED token list:
-    every decode row contributes its one fed-back token, each of up to
-    ``max_prefills`` in-flight prefills its next prompt chunk (each token
-    tagged with its owning slot and absolute position), free slots
-    nothing — chunk KV scatters straight into pool pages, so there is no
-    per-request temp cache and no install copy, and padding never exceeds
-    the static packed width. The per-tick chunk budget
-    (``prefill_chunk`` tokens) is split across the in-flight prefills
-    shortest-remaining-first — short prompts clear the queue fast
-    instead of waiting behind a long one — with the oldest prefill
-    guaranteed a ``budget / max_prefills`` slice so a stream of short
-    prompts can never starve it. When the pool runs out of pages
-    mid-decode the newest request is preempted (freed + requeued) and
-    later *recomputed* — greedy decode makes the recompute
-    token-for-token identical.
-  * ``kv_layout="slots"``: the contiguous :class:`SlotKVPool` — one
-    ``max_len`` region per slot, whole-prompt bucket prefills plus a
-    separate mixed decode call (kept for comparison benchmarks).
-
-Whole-prompt prefill is bucket-padded (one compilation per bucket). With
-``prefill_chunk > 0`` (paged only) prompts instead stream through the
-unified step in chunks drawn from a fixed per-tick token budget shared
-by up to ``max_prefills`` concurrent prefills — decode rows advance in
-the SAME device call, so a long prompt neither stalls running requests
+KV lives in a :class:`PagedKVPool`: pages are claimed block-by-block as
+requests deepen, so HBM is bounded by tokens in flight and ``num_slots``
+can far exceed what ``num_slots * max_len`` contiguous regions would cost.
+Every tick is ONE jitted ``ServeEngine.serve_step`` call over a RAGGED,
+PACKED token list: every decode row contributes its one fed-back token,
+each of up to ``max_prefills`` in-flight prefills its next prompt chunk
+(each token tagged with its owning slot and absolute position), free
+slots nothing. Chunk KV scatters straight into pool pages, so there is
+no per-request temp cache and no install copy, and padding never exceeds
+the static packed width. The per-tick chunk budget (``prefill_chunk``
+tokens) is split across the in-flight prefills shortest-remaining-first —
+short prompts clear the queue fast instead of waiting behind a long one —
+with the oldest prefill guaranteed a ``budget / max_prefills`` slice so a
+stream of short prompts can never starve it. Decode rows advance in the
+SAME device call, so a long prompt neither stalls running requests
 (head-of-line blocking) nor delays *queued* prompts behind it, and no
-batch composition ever costs a second dispatch.
+batch composition ever costs a second dispatch. A budget of at least the
+prompt length runs the whole prompt as one chunk. When the pool runs out
+of pages mid-decode the newest request is preempted (freed + requeued)
+and later *recomputed* — greedy decode makes the recompute
+token-for-token identical.
 
 Because the AoT bias is a per-(task, token) gather from the fused tables
 (paper Eq. 1), the mixed-task batch costs exactly what a single-task batch
@@ -47,10 +34,9 @@ LoRA/Adapters). That zero-cost property is what makes continuous batching
 across tasks free, not just across lengths.
 
 Greedy decode here is token-for-token identical to per-request static
-``ServeEngine.generate``: bucket padding is inert under causal attention,
-per-slot decode writes/reads the same cache rows a dedicated cache would
-(pages are just a scattered layout of those rows), and masked (invalid)
-rows never contribute (see tests/test_serve_scheduler).
+``ServeEngine.generate``: per-slot writes/reads touch the same KV rows a
+dedicated cache would (pages are just a scattered layout of those rows),
+and dead padding tokens never contribute (see tests/test_serve_scheduler).
 
 Stochastic decode (``Request.sampling``) keeps every one of those
 contracts. Each sample owns a counter-based RNG stream —
@@ -73,7 +59,7 @@ import numpy as np
 
 from repro.obs import NULL_OBS, ServeObservability
 from repro.serve.engine import DispatchFault, ServeEngine
-from repro.serve.kv_pool import PagedKVPool, SlotKVPool
+from repro.serve.kv_pool import PagedKVPool
 from repro.serve.recovery import NULL_JOURNAL, RequestJournal
 from repro.serve.sampling import SamplingParams, request_base_key
 
@@ -241,19 +227,14 @@ class Request:
 @dataclass(frozen=True)
 class SchedulerConfig:
     num_slots: int = 8                  # batch width (mixed-step rows)
-    bucket_min: int = 16                # smallest prefill bucket (doubles up)
-    admit_per_step: int = 0             # max prefills between decode steps
-                                        # (0 = fill every free slot)
-    kv_layout: str = "paged"            # "paged" | "slots"
-    block_size: int = 16                # KV page size in tokens (paged)
+    block_size: int = 16                # KV page size in tokens
     num_blocks: int = 0                 # physical pages incl. scratch page 0
-                                        # (0 = capacity parity with slots)
-    prefill_chunk: int = 0              # per-tick prefill TOKEN BUDGET:
+                                        # (0 = one max_len of pages per slot)
+    prefill_chunk: int = 256            # per-tick prefill TOKEN BUDGET (>= 1):
                                         # prompts stream through the unified
                                         # ragged serve step in chunks, the
                                         # budget split across in-flight
                                         # prefills shortest-remaining-first
-                                        # (paged only; 0 = whole-prompt)
     max_prefills: int = 4               # cap on concurrently chunking
                                         # prefills sharing that budget
     prefix_cache_pages: int = 0         # cross-request shared-prefix page
@@ -262,8 +243,7 @@ class SchedulerConfig:
                                         # (LRU) and matched into new
                                         # admissions of the SAME task, so
                                         # chunked prefill starts at the
-                                        # first uncached token (paged +
-                                        # prefill_chunk only; 0 = off)
+                                        # first uncached token (0 = off)
     max_queue: int = 0                  # bounded admission queue: submits
                                         # beyond this many waiters are SHED
                                         # (ShedError) unless they outrank
@@ -278,6 +258,15 @@ class SchedulerConfig:
                                         # after a faulted dispatch or a
                                         # NaN-quarantine before the fault is
                                         # re-raised to the caller
+
+    def __post_init__(self):
+        # reject malformed count knobs (negative / NaN / non-integral) when
+        # the config is built — never as a mid-drain surprise (InvalidConfig)
+        for knob, lo in (("num_slots", 1), ("block_size", 1),
+                         ("num_blocks", 0), ("prefill_chunk", 1),
+                         ("max_prefills", 1), ("prefix_cache_pages", 0),
+                         ("max_queue", 0), ("tick_retries", 0)):
+            _check_count(f"SchedulerConfig.{knob}", getattr(self, knob), lo)
 
 
 @dataclass
@@ -325,55 +314,35 @@ class ContinuousScheduler:
         # default constructed here, not in the signature: a shared default
         # instance would alias across schedulers (mutable-default footgun)
         cfg = cfg if cfg is not None else SchedulerConfig()
-        # reject malformed count knobs (negative / NaN / non-integral) at
-        # construction — never as a mid-drain surprise (InvalidConfig)
-        for knob, lo in (("num_slots", 1), ("bucket_min", 1),
-                         ("admit_per_step", 0), ("block_size", 1),
-                         ("num_blocks", 0), ("prefill_chunk", 0),
-                         ("max_prefills", 1), ("prefix_cache_pages", 0),
-                         ("max_queue", 0), ("tick_retries", 0)):
-            _check_count(f"SchedulerConfig.{knob}", getattr(cfg, knob), lo)
         mcfg = engine.model.cfg
         assert mcfg.causal, (
-            "continuous batching pads prompts to buckets; that is only "
-            "inert under causal attention")
+            "prompts stream through the serve step in chunks; that needs "
+            "causal attention")
         assert not mcfg.prefix_lm_len, (
             f"{mcfg.name}: a bidirectional prefix ({mcfg.prefix_lm_len} "
-            "tokens) attends to bucket padding; continuous batching needs "
+            "tokens) sees tokens of later chunks; continuous batching needs "
             "fully-causal attention")
         kinds = {k for plan in engine.model.plan for k in plan.kinds}
         assert kinds <= {"attn"}, (
-            f"{mcfg.name}: recurrent blocks ({kinds - {'attn'}}) fold bucket "
-            "padding into their state; continuous batching needs "
-            "attention-only stacks (or exact-length prefill) for now")
+            f"{mcfg.name}: recurrent blocks ({kinds - {'attn'}}) have no "
+            "paged state yet; continuous batching needs attention-only "
+            "stacks for now")
         assert mcfg.frontend != "audio_frames", "token requests only"
         method = engine.peft["method"] if engine.peft else "none"
         assert method not in ("ptv1", "ptv2"), (
             f"{method}: prompt/prefix tuning changes cache layout per "
             "request; serve it with static batches")
-        assert cfg.kv_layout in ("paged", "slots"), cfg.kv_layout
-        assert not (cfg.kv_layout == "paged" and mcfg.attn_kind == "swa"
-                    and mcfg.sliding_window), (
-            f"{mcfg.name}: paged decode has no sliding-window masking yet; "
-            "serve SWA models with kv_layout='slots'")
-        assert not (cfg.prefill_chunk > 0 and cfg.kv_layout == "slots"), (
-            "chunked prefill rides the unified paged serve step; "
-            "kv_layout='slots' serves whole-prompt prefills only")
+        assert not (mcfg.attn_kind == "swa" and mcfg.sliding_window), (
+            f"{mcfg.name}: the paged serve path has no sliding-window "
+            "masking yet (ROADMAP B2); ServeEngine.generate serves "
+            "sliding-window models statically")
         self.engine = engine
         self.cfg = cfg
         self.max_len = engine.cfg.max_len
-        if cfg.kv_layout == "paged":
-            self.pool = PagedKVPool(
-                engine.model, cfg.num_slots, self.max_len,
-                block_size=cfg.block_size,
-                num_blocks=cfg.num_blocks or None)
-        else:
-            self.pool = SlotKVPool(engine.model, cfg.num_slots, self.max_len)
+        self.pool = PagedKVPool(
+            engine.model, cfg.num_slots, self.max_len,
+            block_size=cfg.block_size, num_blocks=cfg.num_blocks or None)
         if cfg.prefix_cache_pages > 0:
-            assert cfg.kv_layout == "paged" and cfg.prefill_chunk > 0, (
-                "the prefix cache maps cached pages into block tables and "
-                "starts prefill at the first uncached token — that needs "
-                "kv_layout='paged' with chunked prefill (prefill_chunk > 0)")
             self.pool.enable_prefix_cache(cfg.prefix_cache_pages)
         self.queue = _ClassQueues()
         self.running: Dict[int, Request] = {}        # slot -> request
@@ -419,7 +388,7 @@ class ContinuousScheduler:
         # packed token list: ticks compile to exactly two shapes
         # (decode-only, and decode + up to _qw chunk tokens shared by every
         # in-flight prefill, dead-token padded)
-        self._qw = max(1, cfg.prefill_chunk)
+        self._qw = cfg.prefill_chunk
         # ---- observability (repro.obs) -------------------------------
         # NULL_OBS hands out no-op instruments, so every hook below stays
         # branch-free and costs one attribute lookup when disabled; real
@@ -493,10 +462,6 @@ class ContinuousScheduler:
             "sched_dispatch_faults_total", "serve_step dispatches that "
             "raised (retried up to tick_retries, then re-raised)")
 
-    @property
-    def paged(self) -> bool:
-        return isinstance(self.pool, PagedKVPool)
-
     # ------------------------------------------------------------------
     def _max_new(self, req: Request) -> int:
         sp = req.sampling
@@ -533,10 +498,6 @@ class ContinuousScheduler:
                 sp.validate()
             except ValueError as e:
                 raise InvalidRequest(f"request {req.rid}: {e}") from e
-            if sp.n > 1 and not self.paged:
-                raise InvalidRequest(
-                    f"request {req.rid}: n={sp.n} parallel samples need "
-                    "kv_layout='paged' (COW page forking)")
         max_new = self._max_new(req)
         if max_new < 1:
             raise InvalidRequest(
@@ -600,12 +561,6 @@ class ContinuousScheduler:
         self.obs.slo.on_submit(req, self.ticks)
         self.journal.submit(req, self.ticks)
 
-    def _bucket(self, length: int) -> int:
-        b = self.cfg.bucket_min
-        while b < length:
-            b *= 2
-        return min(b, self.max_len)
-
     def _emit(self, req: Request, tok: int) -> bool:
         """Record one generated token; returns True when the request is done."""
         if not req.out:
@@ -632,7 +587,7 @@ class ContinuousScheduler:
         complete block's KV. Forked sample children retain too: their
         leading pages are the shared prompt pages, and an already-cached
         chain just gets an LRU touch."""
-        cache = getattr(self.pool, "prefix_cache", None)
+        cache = self.pool.prefix_cache
         if cache is not None and req.slot >= 0:
             cache.retain(req.task_id, req.prompt, req.slot)
 
@@ -665,7 +620,7 @@ class ContinuousScheduler:
             self.finished[parent.rid] = parent
 
     # ------------------------------------------------------------------
-    # admission (bucketed prefill; optionally chunked across ticks)
+    # admission (prompts chunked across ticks)
     # ------------------------------------------------------------------
     def _prefill_tokens(self, req: Request) -> np.ndarray:
         """The token sequence whose KV must be resident before decode.
@@ -678,31 +633,18 @@ class ContinuousScheduler:
                                    np.asarray(req.out[:-1], np.int32)])
         return req.prompt
 
-    def _alloc_slot(self, req: Request, length: int) -> Optional[int]:
-        if self.paged:
-            return self.pool.alloc(req.task_id, self.pool.pages_needed(length))
-        return self.pool.alloc(req.task_id)
-
-    def _can_admit(self, req: Request) -> bool:
-        if not self.pool.has_free():
-            return False
-        if self.paged:
-            need = self.pool.pages_needed(len(self._prefill_tokens(req)))
-            return self.pool.free_blocks() >= need
-        return True
-
     def _match_prefix(self, req: Request) -> List[bytes]:
         """Cache keys for the request's longest cached full-page prefix
         ([] without a cache or on a miss). Recomputes after preemption
         match too: their prefill stream begins with the prompt, and the
         chain walk simply stops where the cache's knowledge ends."""
-        cache = getattr(self.pool, "prefix_cache", None)
+        cache = self.pool.prefix_cache
         if cache is None:
             return []
         return cache.match(req.task_id, self._prefill_tokens(req))
 
-    def _can_admit_chunked(self, req: Request) -> bool:
-        """Chunked admission claims the prompt's pages for several ticks
+    def _can_admit(self, req: Request) -> bool:
+        """Admission claims the prompt's pages for several ticks
         before the request emits anything, so it must leave headroom: one
         append page per running decode row stays reserved. Without the
         guard, an aborted prefill requeued at the head is re-admitted on
@@ -722,26 +664,20 @@ class ContinuousScheduler:
         return self.pool.can_claim(need, reserve=len(self.running),
                                    exclude_keys=keys)
 
-    def _first_sample_spec(self, req: Request):
-        """Sampling spec for the first-token draw from the prefill logits.
-
-        None (exact argmax) for greedy singles and for recompute installs —
-        a recomputed request's pending token was already emitted, so its
-        prefill logits are never sampled. A fresh stochastic request draws
-        token 0 under ``fold_in(base_key, 0)``; a fresh n>1 parent draws n
-        first tokens, one per sample stream, from the SAME prefill row."""
+    def _fork_first_spec(self, req: Request):
+        """Sampling spec for a fresh n>1 parent's first tokens: n draws,
+        one per sample stream, from the SAME prefill logits row. None for
+        everything else — a single drew (or argmax'd) its token 0 inside
+        serve_step, and a recomputed request's pending token was already
+        emitted."""
         sp = req.sampling
-        if sp is None or req.out:
+        if sp is None or req.out or req.parent is not None or sp.n == 1:
             return None
-        fresh_parent = req.parent is None and sp.n > 1
-        if sp.greedy and not fresh_parent:
-            return None
-        idxs = list(range(sp.n)) if fresh_parent else [req.sample_idx]
-        n = len(idxs)
+        n = sp.n
         return (np.full(n, sp.temperature, np.float32),
                 np.full(n, sp.top_k, np.int32),
                 np.full(n, sp.top_p, np.float32),
-                np.stack([request_base_key(sp.seed, i) for i in idxs]),
+                np.stack([request_base_key(sp.seed, i) for i in range(n)]),
                 np.zeros(n, np.int32))
 
     def _make_child(self, parent: Request, i: int) -> Request:
@@ -777,13 +713,10 @@ class ContinuousScheduler:
                 self._finish(req)
 
     def _install(self, req: Request, slot: int, length: int,
-                 prefill_toks: List[int], cache=None) -> None:
-        """Publish the prefilled slot and start decoding.
-
-        ``cache`` carries a whole-prompt prefill's contiguous cache to
-        scatter into the pool; ``None`` means the unified serve step
-        already wrote the KV straight into the slot's pages (the chunked
-        path) and only the depth needs committing.
+                 prefill_toks: List[int]) -> None:
+        """Publish the prefilled slot and start decoding. The serve step
+        already wrote the KV straight into the slot's pages, so only the
+        depth needs committing.
 
         A fresh ``n > 1`` request expands here: the prefilled slot becomes
         sample 0, and every other sample forks it copy-on-write (sharing
@@ -791,10 +724,7 @@ class ContinuousScheduler:
         sample is requeued as an independent request instead — its
         counter-based stream makes the tokens identical either way, only
         the prefill sharing is lost."""
-        if cache is not None:
-            self.pool.write_prefill(slot, cache, length)
-        else:
-            self.pool.commit_prefill(slot, length)
+        self.pool.commit_prefill(slot, length)
         sp = req.sampling
         if req.out or req.parent is not None or sp is None or sp.n == 1:
             self._install_single(req, slot, prefill_toks[0])
@@ -820,28 +750,11 @@ class ContinuousScheduler:
         for child in reversed(pending):
             self.queue.appendleft(child)
 
-    def _admit_whole(self, req: Request) -> None:
-        """Whole-prompt path: the entire (bucket-padded) prompt in one
-        prefill call, scattered into the pool at install."""
-        toks_full = self._prefill_tokens(req)
-        s = len(toks_full)
-        slot = self._alloc_slot(req, s)
-        assert slot is not None
-        self._m_admitted.inc()
-        self.obs.slo.on_admit(req, self.ticks)
-        self.journal.admit(req, self.ticks)
-        bucket = self._bucket(s)
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, :s] = toks_full
-        first, cache = self.engine.prefill_request(
-            toks, s, req.task_id, sample=self._first_sample_spec(req))
-        self._install(req, slot, s, first, cache=cache)
-
-    def _start_chunked(self, req: Request) -> None:
+    def _admit(self, req: Request) -> None:
         """Claim a slot + prompt pages; the chunks themselves ride the
         unified serve step as ragged spans of each tick's packed list — no
-        device call here, no temp cache, no bucket padding (the static
-        budget width is the only prefill compilation).
+        device call here and no temp cache (the static budget width is the
+        only prefill compilation).
 
         On a prefix-cache hit the slot's leading pages alias the cached
         prefix (refcount bump, entries pinned until the slot frees) and
@@ -850,13 +763,13 @@ class ContinuousScheduler:
         positions a cold prefill would have written, so the tokens that
         come out are bitwise identical (test-enforced)."""
         toks = self._prefill_tokens(req)
-        cache = getattr(self.pool, "prefix_cache", None)
+        cache = self.pool.prefix_cache
         keys = self._match_prefix(req)
+        need = self.pool.pages_needed(len(toks))
         if keys:
-            slot = self.pool.alloc_cached(
-                req.task_id, keys, self.pool.pages_needed(len(toks)))
+            slot = self.pool.alloc_cached(req.task_id, keys, need)
         else:
-            slot = self._alloc_slot(req, len(toks))
+            slot = self.pool.alloc(req.task_id, need)
         assert slot is not None
         cached = len(keys) * self.cfg.block_size
         if cache is not None:
@@ -899,8 +812,6 @@ class ContinuousScheduler:
         is protected, so admission pressure can delay but never starve an
         already-admitted request: per class, someone always finishes.
         Returns True if a row was preempted (admission should re-check)."""
-        if not self.paged:
-            return False
         rank = PRIORITY_RANK[head.priority]
         protected = self._protected_slots()
         victims = [s for s, req in self.running.items()
@@ -921,7 +832,7 @@ class ContinuousScheduler:
         prompt replay. Only running rows are offered: in-flight prefills'
         pages are still being scattered into by the ragged kernel.
         Returns True when compaction freed at least one page."""
-        if not self.paged or not self.running:
+        if not self.running:
             return False
         freed = self.pool.compact(
             {slot: req.prompt for slot, req in self.running.items()})
@@ -930,35 +841,24 @@ class ContinuousScheduler:
         return freed > 0
 
     def _admission_tick(self) -> None:
-        if self.cfg.prefill_chunk > 0:
-            # starting a chunked prefill is pure host bookkeeping; up to
-            # max_prefills prompts then chunk concurrently through the
-            # single serve_step call each tick, so long prompts never
-            # stall running requests, never serialize queued prompts
-            # behind them, and never cost a dispatch
-            while len(self._prefills) < self.cfg.max_prefills and self.queue:
-                head = self.queue[0]
-                if self._can_admit_chunked(head):
-                    self._start_chunked(self.queue.popleft())
-                elif self._try_compact() and self._can_admit_chunked(head):
-                    # defrag rescued the admission: duplicate prompt pages
-                    # folded together instead of preempting a decode row
-                    self._start_chunked(self.queue.popleft())
-                elif not self._preempt_for_admission(head):
-                    break
-            return
-        lim = self.cfg.admit_per_step or self.cfg.num_slots
-        admitted = 0
-        while self.queue and admitted < lim:
+        # starting a prefill is pure host bookkeeping; up to max_prefills
+        # prompts then chunk concurrently through the single serve_step
+        # call each tick, so long prompts never stall running requests,
+        # never serialize queued prompts behind them, and never cost a
+        # dispatch
+        while len(self._prefills) < self.cfg.max_prefills and self.queue:
             head = self.queue[0]
             if self._can_admit(head):
-                self._admit_whole(self.queue.popleft())
-                admitted += 1
-            elif not (self.paged and self._preempt_for_admission(head)):
+                self._admit(self.queue.popleft())
+            elif self._try_compact() and self._can_admit(head):
+                # defrag rescued the admission: duplicate prompt pages
+                # folded together instead of preempting a decode row
+                self._admit(self.queue.popleft())
+            elif not self._preempt_for_admission(head):
                 break
 
     # ------------------------------------------------------------------
-    # page backpressure (paged layout only)
+    # page backpressure
     # ------------------------------------------------------------------
     def _preempt(self, slot: int) -> None:
         """Free a running request's slot and pages; requeue it at the front
@@ -1062,23 +962,6 @@ class ContinuousScheduler:
                         "paged KV pool cannot hold a single request; raise "
                         "num_blocks (needs >= max_len/block_size + 1)")
 
-    def _decode_sample_spec(self):
-        """Per-slot sampling vectors for this decode step, or None when
-        every running request is greedy (the pure-argmax fast path). Step
-        counters are refreshed from each request's emitted-token count, so
-        the draw for token j is always keyed fold_in(base, j) no matter
-        how the request got here (fresh, forked, or recomputed)."""
-        stochastic = False
-        for slot, req in self.running.items():
-            self.slot_steps[slot] = len(req.out)
-            sp = req.sampling
-            if sp is not None and sp.temperature > 0.0:
-                stochastic = True
-        if not stochastic:
-            return None
-        return (self.slot_temps, self.slot_topk, self.slot_topp,
-                self.slot_keys, self.slot_steps)
-
     # ------------------------------------------------------------------
     # client aborts, deadlines, graceful drain
     # ------------------------------------------------------------------
@@ -1137,10 +1020,7 @@ class ContinuousScheduler:
     def _quarantine_slot(self, slot: int) -> None:
         """Tear down one slot of a poisoned group: the slot frees, its
         exclusively-owned pages go to the pool's quarantine hold."""
-        if self.paged:
-            self.pool.quarantine_slot(slot)
-        else:
-            self.pool.free(slot)
+        self.pool.quarantine_slot(slot)
         self.slot_temps[slot] = 0.0
 
     def quarantine(self, rid: int, reason: str = "nan_logits") -> bool:
@@ -1242,13 +1122,11 @@ class ContinuousScheduler:
         # a shut-down server returns every page: flush the prefix cache
         # (all requests are gone, so nothing is pinned and the flush
         # releases every retained page) before the invariant sweep
-        cache_released = (self.pool.flush_prefix_cache()
-                          if self.paged else 0)
+        cache_released = self.pool.flush_prefix_cache()
         # the forensic quarantine hold does not outlive the process: a
         # shut-down server returns every page (the hold exists to keep
         # poisoned KV dumpable while the server is LIVE)
-        quarantine_released = (self.pool.release_quarantined()
-                               if self.paged else 0)
+        quarantine_released = self.pool.release_quarantined()
         findings = self.drain_check()
         if (self.cfg.check_leaks or self.obs.check_leaks) and findings:
             raise RuntimeError(
@@ -1285,17 +1163,12 @@ class ContinuousScheduler:
 
     # ------------------------------------------------------------------
     def step(self) -> None:
-        """One scheduler tick. Paged: ONE jitted serve_step call over the
-        packed ragged batch of decode tokens + every in-flight prefill's
-        chunk. Slots: whole-prompt admission then a separate mixed decode
-        call (the comparison layout)."""
+        """One scheduler tick: ONE jitted serve_step call over the packed
+        ragged batch of decode tokens + every in-flight prefill's chunk."""
         t0 = time.perf_counter()
         self._expire_deadlines()
         with self.obs.tracer.span("tick", tick=self.ticks):
-            if self.paged:
-                self._paged_tick()
-            else:
-                self._slots_tick()
+            self._paged_tick()
         self.clock += 1
         self.ticks += 1
         self._m_ticks.inc()
@@ -1496,8 +1369,8 @@ class ContinuousScheduler:
                 if pf.done < pf.length:
                     still.append(pf)
                     continue
-                spec = self._first_sample_spec(pf.req)
-                if spec is not None and len(spec[0]) > 1:
+                spec = self._fork_first_spec(pf.req)
+                if spec is not None:
                     # fresh n>1 parent: every sample's token 0 comes from
                     # this one prefill row, each under its own stream (the
                     # only second dispatch, and only on n>1 installs)
@@ -1511,41 +1384,11 @@ class ContinuousScheduler:
             self._prefills = [pf for pf in still
                               if pf.req.state not in TERMINAL_STATES]
         self.peak_running = max(self.peak_running, len(self.running))
-        if tr.enabled and self.paged:
+        if tr.enabled:
             tr.counter("pages", used=self.pool.blocks_in_use(),
                        free=self.pool.free_blocks())
             tr.counter("requests", running=len(self.running),
                        queued=len(self.queue), prefills=len(self._prefills))
-
-    def _slots_tick(self) -> None:
-        """The contiguous-layout tick: bucketed whole-prompt admission,
-        then one mixed decode call over all occupied slots."""
-        tr = self.obs.tracer
-        with tr.span("admission", queued=len(self.queue)):
-            self._admission_tick()
-        if self.running:
-            sample = self._decode_sample_spec()
-            self._m_tick_tokens.observe(len(self.running))
-            with tr.span("dispatch", tokens=len(self.running)):
-                toks, cache = self.engine.decode_mixed(
-                    self.slot_tokens, self.pool.cur_len, self.pool.cache,
-                    self.pool.task_id, sample=sample)
-            self.pool.cache = cache
-            with tr.span("postprocess"):
-                active = list(self.running.items())
-                self.peak_running = max(self.peak_running, len(active))
-                self.pool.advance([s for s, _ in active])
-                self.steps_decoded += 1
-                for slot, req in active:
-                    if self.running.get(slot) is not req:
-                        continue    # aborted mid-postprocess (on_token)
-                    tok = int(toks[slot])
-                    self.slot_tokens[slot, 0] = tok
-                    done = self._emit(req, tok)
-                    if self.running.get(slot) is not req:
-                        continue    # on_token aborted this very request
-                    if done:
-                        self._finish(req)
 
     def busy(self) -> bool:
         """Anything left to do: queued, decoding, or mid-prefill."""

@@ -62,7 +62,7 @@ def _requests(cfg, n, sampled=False, seed=0):
 
 def _serve(eng, reqs, obs=None):
     sched = ContinuousScheduler(eng, SchedulerConfig(
-        num_slots=SLOTS, bucket_min=8, kv_layout="paged", block_size=BLOCK,
+        num_slots=SLOTS, block_size=BLOCK,
         prefill_chunk=8), obs=obs)
     for r in reqs:
         sched.submit(r)

@@ -8,7 +8,6 @@ from repro.kernels import ref as R
 from repro.kernels.aot_bias import (aot_gather_add_kernel,
                                     aot_gather_add_multitask_kernel)
 from repro.kernels.decode_attention import (decode_attention_kernel,
-                                            paged_decode_attention_kernel,
                                             round_kv_len)
 from repro.kernels.flash_attention import flash_attention_kernel
 
@@ -62,55 +61,6 @@ def test_decode_attention_ragged_lens(rng, b, h, kvh, hd, S, dtype):
     out = decode_attention_kernel(q, kc, vc, lens, block_k=16, interpret=True)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out, np.float32),
-                               atol=tol, rtol=tol)
-
-
-def _page_scatter(rng, b, S, bs, num_blocks, lens):
-    """Random non-overlapping page assignment for each row's resident pages."""
-    npages = -(-S // bs)
-    bt = np.zeros((b, npages), np.int32)
-    # page 0 is the serve pool's scratch page; never map it
-    avail = list(rng.permutation(np.arange(1, num_blocks)))
-    for i in range(b):
-        for j in range(-(-int(lens[i]) // bs)):
-            bt[i, j] = avail.pop()
-    return bt
-
-
-@pytest.mark.parametrize("b,h,kvh,hd,bs,nb", [(3, 4, 2, 16, 8, 24),
-                                              (2, 8, 1, 32, 16, 12),
-                                              (4, 2, 2, 8, 8, 40)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_decode_attention(rng, b, h, kvh, hd, bs, nb, dtype):
-    """Block-table flash-decode == paged oracle == contiguous oracle over
-    the gathered rows, with scrambled page assignments and ragged depths."""
-    S = (nb - 1) // b * bs                       # rows can't overdraw pages
-    t = lambda *sh: jnp.asarray(rng.normal(size=sh), dtype)
-    q, kp, vp = t(b, h, hd), t(nb, bs, kvh, hd), t(nb, bs, kvh, hd)
-    lens = rng.integers(0, S + 1, (b,)).astype(np.int32)
-    lens[0] = S                                  # cover full + empty rows
-    lens[-1] = 0
-    bt = jnp.asarray(_page_scatter(rng, b, S, bs, nb, lens))
-    lensj = jnp.asarray(lens)
-    ref = R.paged_decode_attention_ref(q.astype(jnp.float32),
-                                       kp.astype(jnp.float32),
-                                       vp.astype(jnp.float32), bt, lensj)
-    out = paged_decode_attention_kernel(q, kp, vp, bt, lensj, interpret=True)
-    tol = 2e-5 if dtype == jnp.float32 else 2e-2
-    live = lens > 0
-    np.testing.assert_allclose(np.asarray(ref)[live],
-                               np.asarray(out, np.float32)[live],
-                               atol=tol, rtol=tol)
-    assert np.all(np.asarray(out)[~live] == 0), "empty rows must be zeros"
-    # a paged cache is just a scattered layout: the contiguous oracle over
-    # the gathered rows agrees too
-    kc = jnp.take(kp, bt, axis=0).reshape(b, -1, kvh, hd)
-    vc = jnp.take(vp, bt, axis=0).reshape(b, -1, kvh, hd)
-    contig = R.decode_attention_ref(q.astype(jnp.float32),
-                                    kc.astype(jnp.float32),
-                                    vc.astype(jnp.float32), lensj)
-    np.testing.assert_allclose(np.asarray(contig)[live],
-                               np.asarray(out, np.float32)[live],
                                atol=tol, rtol=tol)
 
 

@@ -59,7 +59,7 @@ def _mk_requests(rng, cfg, n, sampled=False):
 
 def _serve(eng, reqs, obs=None, **sched_kw):
     sched = ContinuousScheduler(eng, SchedulerConfig(
-        num_slots=3, bucket_min=8, kv_layout="paged", block_size=8,
+        num_slots=3, block_size=8,
         prefill_chunk=8, **sched_kw), obs=obs)
     arrivals = [(i % 5, r) for i, r in enumerate(reqs)]
     return sched, sched.run_stream(arrivals)
@@ -312,7 +312,7 @@ def test_drain_leak_check_fires_on_injected_leak(rng, obs_engine):
     cfg, eng = obs_engine
     obs = ServeObservability(metrics=True)
     sched = ContinuousScheduler(eng, SchedulerConfig(
-        num_slots=3, bucket_min=8, kv_layout="paged", block_size=8,
+        num_slots=3, block_size=8,
         prefill_chunk=8, check_leaks=True), obs=obs)
     for r in _mk_requests(rng, cfg, 3):
         sched.submit(r)
@@ -364,7 +364,7 @@ def test_slo_ttft_matches_external_measurement(rng, obs_engine):
     cfg, eng = obs_engine
     obs = ServeObservability(metrics=True)
     sched = ContinuousScheduler(eng, SchedulerConfig(
-        num_slots=3, bucket_min=8, kv_layout="paged", block_size=8,
+        num_slots=3, block_size=8,
         prefill_chunk=8), obs=obs)
     submit_tick, first_tick = {}, {}
     reqs = _mk_requests(rng, cfg, 6)

@@ -53,7 +53,7 @@ def mt_engine(tiny_lm):
 
 
 def _sched(eng, **kw):
-    base = dict(num_slots=4, bucket_min=8, kv_layout="paged", block_size=BS,
+    base = dict(num_slots=4, block_size=BS,
                 prefill_chunk=16, prefix_cache_pages=16)
     base.update(kw)
     return ContinuousScheduler(eng, SchedulerConfig(**base))
@@ -378,7 +378,7 @@ def test_slo_summary_warm_vs_cold(rng, mt_engine):
     cfg, eng = mt_engine
     obs = ServeObservability(metrics=True)
     sched = ContinuousScheduler(eng, SchedulerConfig(
-        num_slots=4, kv_layout="paged", block_size=BS, prefill_chunk=16,
+        num_slots=4, block_size=BS, prefill_chunk=16,
         prefix_cache_pages=16), obs=obs)
     sys_p = _tokens(rng, 3 * BS)
     for i in range(3):
@@ -582,7 +582,7 @@ def _prefix_workload(cfg, seed, n):
 
 def _prefix_chaos_sched(eng, cached):
     return ContinuousScheduler(eng, SchedulerConfig(
-        num_slots=3, bucket_min=8, kv_layout="paged", block_size=BS,
+        num_slots=3, block_size=BS,
         prefill_chunk=8, num_blocks=14,
         prefix_cache_pages=8 if cached else 0))
 

@@ -7,15 +7,16 @@ Contracts under test:
   batch composition a scheduler tick can pack — decode-only, prefill-only,
   mixed, dead padding tokens, chunks straddling page boundaries;
 * the XLA fallback (``layers.ragged_paged_attention_decode``) obeys the
-  same oracle, and collapses to the paged decode computation per token;
-* ``model.mixed_step`` with decode tokens is BITWISE the paged
+  same oracle, and a decode token collapses to the contiguous decode
+  oracle over its slot's gathered pages;
+* ``model.mixed_step`` with decode tokens is BITWISE the contiguous
   ``decode_step``, and a chunked ragged prefill reproduces the
   whole-prompt prefill logits;
 * a scheduler tick with prefill chunks and decode rows in flight issues
   exactly ONE jitted device call — including with SEVERAL prompts
   chunking concurrently (multi-prefill packing) — and the unified tick's
-  token streams are identical to the whole-prompt two-call path, to
-  serial single-prefill admission, and to static per-request decode;
+  token streams are identical across prefill budgets, to serial
+  single-prefill admission, and to static per-request decode;
 * the per-tick chunk budget splits shortest-remaining-first, so a short
   prompt overtakes a long one mid-prefill (no prefill head-of-line
   blocking).
@@ -52,10 +53,18 @@ def _tables_for(rng, ns, bs, nb, depths):
 # and the widths it runs at: (heads, kv heads, head_dim, block_size,
 # pool pages)
 SMALL = (4, 2, 16, 8, 40)
+DECODE_G2 = (4, 2, 16, 8, 24)     # 23 usable pages
+DECODE_G8 = (8, 1, 32, 16, 12)    # g=8 over one KV head of 32, 11 pages
+DECODE_HD8 = (2, 2, 8, 8, 40)     # head_dim 8: no lane packing
 SMOLLM = (6, 2, 64, 16, 96)       # smollm-360m's g=3, hd 64, 16-token pages
 OLMO = (2, 2, 128, 16, 40)        # olmo-1b's g=1, hd 128
 COMPOSITIONS = {
     "decode_only": ([0, 1, 2, 3], [13, 5, 0, 26], SMALL),
+    # decode ticks over small pools: 3, 2 and 4 live slots at ragged
+    # depths, one of them as deep as its share of the pool
+    "decode_3slots_g2": ([0, 1, 2], [55, 20, 0], DECODE_G2),
+    "decode_2slots_g8": ([3, 0], [79, 36], DECODE_G8),
+    "decode_4slots_hd8": ([0, 1, 2, 3], [71, 29, 8, 0], DECODE_HD8),
     "prefill_only": ([1, 1, 1, 1, 1, 1], [0, 1, 2, 3, 4, 5], SMALL),
     "mixed": ([0, 2, 1, 1, 1, 1, 3], [13, 3, 5, 6, 7, 8, 0], SMALL),
     "dead_tokens": ([1, 0, 0, 0], [9, -1, -1, -1], SMALL),
@@ -126,9 +135,10 @@ def test_ragged_xla_fallback_matches_oracle(rng):
 
 
 def test_ragged_decode_token_equals_paged_decode(rng):
-    """A decode token (one per slot, pos = depth - 1) reproduces the paged
-    flash-decode oracle at cur_len = pos + 1 — the ragged kernel strictly
-    generalizes the paged decode contract."""
+    """A decode token (one per slot, pos = depth - 1) reproduces the
+    contiguous flash-decode oracle over its slot's pages gathered in
+    order, at cur_len = pos + 1: a paged cache is just a scattered layout
+    of the same KV rows."""
     ns, h, kvh, hd, bs, nb = 3, 4, 2, 16, 8, 24
     t = lambda *sh: jnp.asarray(rng.normal(size=sh), jnp.float32)
     q, kp, vp = t(ns, h, hd), t(nb, bs, kvh, hd), t(nb, bs, kvh, hd)
@@ -136,8 +146,10 @@ def test_ragged_decode_token_equals_paged_decode(rng):
     rows = jnp.arange(ns, dtype=jnp.int32)
     bt = _tables_for(rng, ns, bs, nb, np.asarray(pos) + 1)
     ragged = R.ragged_paged_attention_ref(q, kp, vp, bt, rows, pos)
-    paged = R.paged_decode_attention_ref(q, kp, vp, bt, pos + 1)
-    np.testing.assert_allclose(np.asarray(ragged), np.asarray(paged),
+    kc = jnp.take(kp, bt, axis=0).reshape(ns, -1, kvh, hd)
+    vc = jnp.take(vp, bt, axis=0).reshape(ns, -1, kvh, hd)
+    contig = R.decode_attention_ref(q, kc, vc, pos + 1)
+    np.testing.assert_allclose(np.asarray(ragged), np.asarray(contig),
                                atol=1e-6, rtol=1e-6)
 
 
@@ -328,7 +340,8 @@ def _paged_from_contiguous(rng, model, cache, depths, bs_page, nblocks,
 
 
 def test_mixed_step_decode_tokens_bitwise_decode_step(rng, tiny_lm):
-    """Decode-token mixed_step logits == paged decode_step logits, bitwise."""
+    """Decode-token mixed_step logits over scrambled pool pages ==
+    contiguous decode_step logits over the same KV rows, bitwise."""
     cfg, model, params = tiny_lm
     b, s, bs_page, nblocks = 3, 8, 4, 14
     toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (b, s)), jnp.int32)
@@ -338,8 +351,7 @@ def test_mixed_step_decode_tokens_bitwise_decode_step(rng, tiny_lm):
                                        nblocks)
     step_tok = jnp.asarray(rng.integers(0, cfg.vocab_size, (b, 1)), jnp.int32)
     pos = jnp.asarray(depths)
-    lg_dec, _ = model.decode_step(params, step_tok, pos, paged,
-                                  block_tables=bt)
+    lg_dec, _ = model.decode_step(params, step_tok, pos, cache)
     lg_mix, _ = model.mixed_step(params, step_tok,
                                  jnp.arange(b, dtype=jnp.int32), pos, paged,
                                  block_tables=bt,
@@ -421,7 +433,7 @@ def test_unified_tick_is_one_dispatch(rng, mt_engine):
     flight costs exactly one jitted device call."""
     cfg, eng = mt_engine
     sched = ContinuousScheduler(eng, SchedulerConfig(
-        num_slots=4, bucket_min=8, kv_layout="paged", block_size=8,
+        num_slots=4, block_size=8,
         prefill_chunk=8))
     short = Request(rid=0, prompt=rng.integers(0, cfg.vocab_size, 4)
                     .astype(np.int32), task_id=0, max_new_tokens=12)
@@ -453,7 +465,7 @@ def test_unified_tick_is_one_dispatch(rng, mt_engine):
 def test_decode_only_tick_is_one_dispatch(rng, mt_engine):
     cfg, eng = mt_engine
     sched = ContinuousScheduler(eng, SchedulerConfig(
-        num_slots=3, bucket_min=8, kv_layout="paged", block_size=8,
+        num_slots=3, block_size=8,
         prefill_chunk=8))
     for i in range(2):
         sched.submit(Request(
@@ -468,9 +480,9 @@ def test_decode_only_tick_is_one_dispatch(rng, mt_engine):
 
 
 def test_unified_vs_whole_prompt_token_parity(rng, mt_engine):
-    """The unified chunked tick and the whole-prompt (separate prefill
-    dispatch) paged path produce identical token streams — the old
-    two-call tick's outputs survive the merge."""
+    """Token streams do not depend on the prefill budget: prompts chunked
+    4 or 16 tokens a tick, and whole prompts as one chunk (a budget of 48,
+    at least max_len), decode identically."""
     cfg, eng = mt_engine
 
     def mk():
@@ -483,17 +495,17 @@ def test_unified_vs_whole_prompt_token_parity(rng, mt_engine):
             max_new_tokens=int(rr.integers(1, 9))) for i in range(6)]
 
     outs = []
-    for kw in (dict(prefill_chunk=8), dict()):
+    for budget in (4, 16, 48):
         reqs = mk()
         sched = ContinuousScheduler(eng, SchedulerConfig(
-            num_slots=3, bucket_min=8, kv_layout="paged", block_size=8, **kw))
+            num_slots=3, block_size=8, prefill_chunk=budget))
         for r in reqs:
             sched.submit(r)
         sched.run()
         sched.pool.check_no_leaks()
         outs.append([r.out for r in reqs])
-    assert outs[0] == outs[1], (
-        "unified chunked tick diverged from whole-prompt admission")
+    assert outs[0] == outs[1] == outs[2], (
+        "token streams depend on the prefill budget")
 
 
 def test_multi_prefill_one_dispatch_per_tick(rng, mt_engine):
@@ -502,7 +514,7 @@ def test_multi_prefill_one_dispatch_per_tick(rng, mt_engine):
     dispatches/ticks == 1.0 over the whole greedy workload."""
     cfg, eng = mt_engine
     sched = ContinuousScheduler(eng, SchedulerConfig(
-        num_slots=6, bucket_min=8, kv_layout="paged", block_size=8,
+        num_slots=6, block_size=8,
         prefill_chunk=8, max_prefills=3))
     reqs = [Request(rid=i,
                     prompt=rng.integers(0, cfg.vocab_size, 20 + 4 * i)
@@ -546,7 +558,7 @@ def test_multi_prefill_bitwise_matches_serial_admission(rng, mt_engine):
     for k in (4, 1):
         reqs = mk()
         sched = ContinuousScheduler(eng, SchedulerConfig(
-            num_slots=4, bucket_min=8, kv_layout="paged", block_size=8,
+            num_slots=4, block_size=8,
             prefill_chunk=8, max_prefills=k))
         for r in reqs:
             sched.submit(r)
@@ -567,7 +579,7 @@ def test_budget_split_shortest_remaining_first(rng, mt_engine):
     the head-of-line-blocking fix this PR exists for."""
     cfg, eng = mt_engine
     sched = ContinuousScheduler(eng, SchedulerConfig(
-        num_slots=4, bucket_min=8, kv_layout="paged", block_size=8,
+        num_slots=4, block_size=8,
         prefill_chunk=8, max_prefills=2))
     first_tick = {}
 
@@ -599,7 +611,7 @@ def test_oldest_prefill_never_starved_by_short_stream(rng, mt_engine):
     max_prefills * prompt / budget ticks regardless of arrival load."""
     cfg, eng = mt_engine
     sched = ContinuousScheduler(eng, SchedulerConfig(
-        num_slots=6, bucket_min=8, kv_layout="paged", block_size=8,
+        num_slots=6, block_size=8,
         prefill_chunk=8, max_prefills=2))
     long = Request(rid=0, prompt=rng.integers(0, cfg.vocab_size, 40)
                    .astype(np.int32), task_id=0, max_new_tokens=2)
@@ -625,18 +637,20 @@ def test_oldest_prefill_never_starved_by_short_stream(rng, mt_engine):
 
 
 def test_chunked_prefill_no_temp_cache_copies(rng, mt_engine):
-    """The chunked path must not route through write_prefill (the install
-    copy) — chunk KV lands in the pool pages directly."""
+    """Chunk KV lands in the pool pages directly: a 20-token prompt is
+    three chunks of the serve step, one dispatch a tick, and the request
+    then decodes exactly as a dedicated static cache would."""
     cfg, eng = mt_engine
     sched = ContinuousScheduler(eng, SchedulerConfig(
-        num_slots=2, bucket_min=8, kv_layout="paged", block_size=8,
+        num_slots=2, block_size=8,
         prefill_chunk=8))
-    calls = []
-    orig = sched.pool.write_prefill
-    sched.pool.write_prefill = lambda *a, **k: (calls.append(1), orig(*a, **k))
-    sched.submit(Request(
+    req = Request(
         rid=0, prompt=rng.integers(0, cfg.vocab_size, 20).astype(np.int32),
-        task_id=0, max_new_tokens=3))
+        task_id=0, max_new_tokens=3)
+    d0 = eng.dispatches
+    sched.submit(req)
     sched.run()
-    assert not calls, "chunked prefill still copies through write_prefill"
     assert sched.prefill_chunks_run == 3    # 20 tokens / 8-chunk = 3 chunks
+    assert eng.dispatches - d0 == sched.ticks
+    ref = eng.generate(req.prompt[None], 3, np.asarray([0], np.int32))[0]
+    np.testing.assert_array_equal(np.asarray(req.out), ref)

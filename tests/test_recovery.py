@@ -50,7 +50,7 @@ def mt_engine(tiny_lm):
 
 
 def _sched(eng, journal=None, obs=None, **kw):
-    base = dict(num_slots=3, bucket_min=8, kv_layout="paged", block_size=8,
+    base = dict(num_slots=3, block_size=8,
                 prefill_chunk=8, num_blocks=14)
     base.update(kw)
     return ContinuousScheduler(eng, SchedulerConfig(**base), obs=obs,
@@ -526,13 +526,13 @@ def test_compact_rescues_admission_before_preempt(mt_engine, rng):
 
 @pytest.mark.parametrize("knob,value", [
     ("num_slots", 0), ("num_slots", -1), ("num_slots", 2.5),
-    ("num_slots", float("nan")), ("bucket_min", 0), ("block_size", -8),
+    ("num_slots", float("nan")), ("prefill_chunk", 0), ("block_size", -8),
     ("tick_retries", -1), ("max_prefills", 0), ("prefill_chunk", -1),
     ("num_blocks", float("inf")), ("max_queue", -3),
 ])
 def test_invalid_config_rejected_at_construction(mt_engine, knob, value):
     cfg, eng = mt_engine
-    kw = dict(num_slots=2, kv_layout="paged", block_size=8, prefill_chunk=8)
+    kw = dict(num_slots=2, block_size=8, prefill_chunk=8)
     kw[knob] = value
     with pytest.raises(InvalidConfig, match=knob):
         ContinuousScheduler(eng, SchedulerConfig(**kw))

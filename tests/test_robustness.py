@@ -88,7 +88,7 @@ def test_invalid_request_rejected(mt_engine, variant):
     leaves the scheduler exactly as it was: nothing queued, pool clean."""
     cfg, eng = mt_engine
     sched = ContinuousScheduler(eng, SchedulerConfig(
-        num_slots=2, kv_layout="paged", block_size=8, prefill_chunk=8))
+        num_slots=2, block_size=8, prefill_chunk=8))
     with pytest.raises(InvalidRequest):
         sched.submit(_invalid_variants()[variant])
     assert len(sched.queue) == 0 and not sched.running
@@ -110,7 +110,7 @@ def test_invalid_request_is_value_error(mt_engine):
 # ---------------------------------------------------------------------------
 
 def _abort_sched(eng, **kw):
-    base = dict(num_slots=3, bucket_min=8, kv_layout="paged", block_size=8,
+    base = dict(num_slots=3, block_size=8,
                 prefill_chunk=8)
     base.update(kw)
     return ContinuousScheduler(eng, SchedulerConfig(**base))
@@ -211,7 +211,7 @@ def test_priority_admission_order(rng, mt_engine):
     regardless of submission order."""
     cfg, eng = mt_engine
     sched = ContinuousScheduler(eng, SchedulerConfig(
-        num_slots=1, bucket_min=8, kv_layout="paged", block_size=8))
+        num_slots=1, block_size=8, prefill_chunk=8))
     be = _req(cfg, rng, 0, plen=8, max_new=4, priority=BEST_EFFORT)
     st = _req(cfg, rng, 1, plen=8, max_new=4, priority=STANDARD)
     lat = _req(cfg, rng, 2, plen=8, max_new=4, priority=LATENCY)
@@ -232,7 +232,7 @@ def test_latency_preempts_best_effort_for_pages(rng, mt_engine):
     cfg, eng = mt_engine
     obs = ServeObservability(metrics=True, trace=False)
     sched = ContinuousScheduler(eng, SchedulerConfig(
-        num_slots=3, bucket_min=8, kv_layout="paged", block_size=8,
+        num_slots=3, block_size=8,
         prefill_chunk=8, num_blocks=9), obs=obs)
     be = [_req(cfg, rng, i, plen=16, max_new=10, priority=BEST_EFFORT)
           for i in range(2)]
@@ -260,7 +260,7 @@ def test_sustained_latency_cannot_starve_admitted_best_effort(rng, mt_engine):
     and admission pressure wants its pages."""
     cfg, eng = mt_engine
     sched = ContinuousScheduler(eng, SchedulerConfig(
-        num_slots=3, bucket_min=8, kv_layout="paged", block_size=8,
+        num_slots=3, block_size=8,
         prefill_chunk=8, num_blocks=10))
     be = _req(cfg, rng, 0, plen=16, max_new=12, priority=BEST_EFFORT)
     arrivals = [(0, be)]
@@ -312,7 +312,7 @@ def test_deadline_met_is_untouched(rng, mt_engine):
 def test_queue_full_sheds_with_reason(rng, mt_engine):
     cfg, eng = mt_engine
     sched = ContinuousScheduler(eng, SchedulerConfig(
-        num_slots=1, bucket_min=8, kv_layout="paged", block_size=8,
+        num_slots=1, block_size=8, prefill_chunk=8,
         max_queue=2))
     reqs = [_req(cfg, rng, i, plen=8, max_new=4) for i in range(4)]
     sched.submit(reqs[0])
@@ -333,7 +333,7 @@ def test_higher_class_displaces_queued_best_effort(rng, mt_engine):
     best-effort request instead of being refused."""
     cfg, eng = mt_engine
     sched = ContinuousScheduler(eng, SchedulerConfig(
-        num_slots=1, bucket_min=8, kv_layout="paged", block_size=8,
+        num_slots=1, block_size=8, prefill_chunk=8,
         max_queue=2))
     r0 = _req(cfg, rng, 0, plen=8, max_new=4)
     sched.submit(r0)
@@ -406,7 +406,7 @@ def _chaos_workload(cfg, seed, n=10, stochastic=False):
 
 def _chaos_sched(eng):
     return ContinuousScheduler(eng, SchedulerConfig(
-        num_slots=3, bucket_min=8, kv_layout="paged", block_size=8,
+        num_slots=3, block_size=8,
         prefill_chunk=8, num_blocks=14))
 
 

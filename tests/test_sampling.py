@@ -216,10 +216,14 @@ def test_fork_cow_preserves_shared_content(rng, tiny_lm):
     pool = PagedKVPool(model, num_slots=2, max_len=16, block_size=4,
                        num_blocks=10)
     slot = pool.alloc(npages=2)
-    # write a recognizable prefill: 6 real tokens (tail page half full)
-    toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (1, 8)), jnp.int32)
-    _, cache, _ = model.prefill(params, {"tokens": toks}, max_len=8)
-    pool.write_prefill(slot, cache, 6)
+    # fill the slot's two pages with recognizable KV and publish 6 real
+    # tokens (tail page half full), as a finished prefill leaves them
+    pages = jnp.asarray(pool._pages[slot])
+    pool.cache = jax.tree.map(
+        lambda leaf: leaf.at[:, pages].set(jnp.asarray(
+            rng.normal(size=leaf[:, pages].shape), leaf.dtype)),
+        pool.cache)
+    pool.commit_prefill(slot, 6)
     fork = pool.fork(slot)
     assert pool.ensure_append_page(fork)        # COW the shared tail
     assert pool.cow_copies == 1
@@ -290,7 +294,8 @@ def _stoch_requests(rng, cfg, n=8):
 
 
 def _run_all(eng, reqs, **cfg_kw):
-    sched = ContinuousScheduler(eng, SchedulerConfig(bucket_min=8, **cfg_kw))
+    cfg_kw.setdefault("prefill_chunk", 8)
+    sched = ContinuousScheduler(eng, SchedulerConfig(**cfg_kw))
     for r in reqs:
         sched.submit(r)
     sched.run()
@@ -300,18 +305,18 @@ def _run_all(eng, reqs, **cfg_kw):
 
 def test_sampled_stream_batch_invariant(rng, mt_engine):
     """Sampled tokens depend only on (seed, sample_idx, step): the same
-    requests produce identical tokens at different batch widths/layouts."""
+    requests produce identical tokens at different batch widths and
+    prefill budgets (48 >= max_len: every prompt in one chunk)."""
     cfg, eng = mt_engine
     outs = []
-    for kw in (dict(num_slots=3, kv_layout="paged", block_size=8),
-               dict(num_slots=5, kv_layout="paged", block_size=8,
-                    prefill_chunk=8),
-               dict(num_slots=2, kv_layout="slots")):
+    for kw in (dict(num_slots=3, block_size=8, prefill_chunk=48),
+               dict(num_slots=5, block_size=8,
+                    prefill_chunk=8)):
         rng_r = np.random.default_rng(7)
         reqs = _stoch_requests(rng_r, cfg)
         _run_all(eng, reqs, **kw)
         outs.append([r.out for r in reqs])
-    assert outs[0] == outs[1] == outs[2], "sampling depends on composition"
+    assert outs[0] == outs[1], "sampling depends on composition"
 
 
 def test_sampled_preempt_recompute_exact(rng, mt_engine):
@@ -320,11 +325,11 @@ def test_sampled_preempt_recompute_exact(rng, mt_engine):
     cfg, eng = mt_engine
     rng_r = np.random.default_rng(2)
     reqs_free = _stoch_requests(rng_r, cfg)
-    _run_all(eng, reqs_free, num_slots=3, kv_layout="paged", block_size=8)
+    _run_all(eng, reqs_free, num_slots=3, block_size=8)
 
     rng_r = np.random.default_rng(2)
     reqs_tight = _stoch_requests(rng_r, cfg)
-    sched = _run_all(eng, reqs_tight, num_slots=4, kv_layout="paged",
+    sched = _run_all(eng, reqs_tight, num_slots=4,
                      block_size=8, num_blocks=9)
     assert sched.preemptions > 0, "pool was sized to force preemption"
     for a, b in zip(reqs_free, reqs_tight):
@@ -362,10 +367,10 @@ def test_fork_divergence_parity_vs_independent_slots(rng, mt_engine):
                        sampling=SamplingParams(temperature=0.8, top_p=0.9,
                                                seed=21, n=4))
     forked = nreq()
-    s1 = _run_all(eng, [forked], num_slots=6, kv_layout="paged", block_size=8)
+    s1 = _run_all(eng, [forked], num_slots=6, block_size=8)
     assert s1.pool.forks == 3 and s1.pool.cow_copies > 0
     indep = nreq()
-    s2 = _run_all(eng, [indep], num_slots=1, kv_layout="paged", block_size=8)
+    s2 = _run_all(eng, [indep], num_slots=1, block_size=8)
     assert s2.pool.forks == 0
     assert forked.samples == indep.samples, (
         "forked COW samples diverged from independent decodes")
@@ -387,7 +392,7 @@ def test_fork_shares_prompt_pages(rng, mt_engine):
         req = Request(rid=0, prompt=prompt, task_id=0, max_new_tokens=3,
                       sampling=SamplingParams(temperature=0.7, seed=3, n=n))
         sched = ContinuousScheduler(eng, SchedulerConfig(
-            num_slots=6, bucket_min=8, kv_layout="paged", block_size=4))
+            num_slots=6, block_size=4, prefill_chunk=8))
         sched.submit(req)
         peak = 0
         while sched.busy():
@@ -399,16 +404,6 @@ def test_fork_shares_prompt_pages(rng, mt_engine):
     p1, p4 = peak_pages(1), peak_pages(4)
     assert p4 < 1.5 * p1, (
         f"n=4 used {p4} pages vs {p1} single — forking is not sharing")
-
-
-def test_n_gt_1_requires_paged_layout(rng, mt_engine):
-    cfg, eng = mt_engine
-    sched = ContinuousScheduler(eng, SchedulerConfig(num_slots=2,
-                                                     kv_layout="slots"))
-    with pytest.raises(ValueError, match="paged"):
-        sched.submit(Request(
-            rid=0, prompt=rng.integers(0, cfg.vocab_size, 4).astype(np.int32),
-            sampling=SamplingParams(temperature=0.5, n=2)))
 
 
 def test_stop_tokens_and_max_tokens_override(rng, mt_engine):

@@ -1,4 +1,4 @@
-"""Continuous-batching scheduler + slotted KV pool.
+"""Continuous-batching scheduler + paged KV pool.
 
 The contract under test: serving a mixed-task request stream continuously
 (staggered arrivals, heterogeneous prompt/output lengths, slot churn) is
@@ -11,7 +11,7 @@ import pytest
 
 from repro.core import aot as A
 from repro.serve.engine import ServeConfig, ServeEngine
-from repro.serve.kv_pool import PagedKVPool, SlotKVPool
+from repro.serve.kv_pool import PagedKVPool
 from repro.serve.scheduler import (ContinuousScheduler, Request,
                                    SchedulerConfig)
 
@@ -25,23 +25,18 @@ def mt_engine(tiny_lm):
                             fused_tasks=tasks)
 
 
-SCHED_VARIANTS = {
-    "slots": dict(kv_layout="slots"),
-    "paged": dict(kv_layout="paged", block_size=8),
-    "paged_chunked": dict(kv_layout="paged", block_size=8, prefill_chunk=8),
-}
-
-
-@pytest.mark.parametrize("variant", sorted(SCHED_VARIANTS))
-def test_continuous_matches_static(rng, mt_engine, variant):
+# per-tick prefill budgets: under a page (3), not a divisor of the page
+# (5), one page (8), and at least max_len (48: every prompt runs as one
+# chunk)
+@pytest.mark.parametrize("prefill_chunk", [3, 5, 8, 48])
+def test_continuous_matches_static(rng, mt_engine, prefill_chunk):
     """Mixed-task stream through the continuous scheduler == per-request
-    static greedy decode, token for token — for the contiguous slotted
-    pool, the paged pool, and the paged pool with chunked prefill.
+    static greedy decode, token for token, at every prefill budget.
     Staggered arrivals, ragged prompt lengths, ragged output lengths,
     fewer slots than requests."""
     cfg, eng = mt_engine
     sched = ContinuousScheduler(eng, SchedulerConfig(
-        num_slots=3, bucket_min=8, **SCHED_VARIANTS[variant]))
+        num_slots=3, block_size=8, prefill_chunk=prefill_chunk))
     reqs, arrivals = [], []
     for i in range(8):
         plen = int(rng.integers(3, 17))
@@ -59,7 +54,8 @@ def test_continuous_matches_static(rng, mt_engine, variant):
                            np.asarray([req.task_id], np.int32))[0]
         np.testing.assert_array_equal(
             np.asarray(finished[req.rid].out), ref,
-            err_msg=f"req {req.rid} (task {req.task_id}) diverged ({variant})")
+            err_msg=f"req {req.rid} (task {req.task_id}) diverged "
+                    f"(prefill_chunk {prefill_chunk})")
 
 
 def test_paged_preemption_recompute_exact(rng, mt_engine):
@@ -68,8 +64,8 @@ def test_paged_preemption_recompute_exact(rng, mt_engine):
     cfg, eng = mt_engine
     # 48-token max_len -> 6 pages of 8; 11 usable pages forces churn
     sched = ContinuousScheduler(eng, SchedulerConfig(
-        num_slots=4, bucket_min=8, kv_layout="paged", block_size=8,
-        num_blocks=12))
+        num_slots=4, block_size=8,
+        num_blocks=12, prefill_chunk=8))
     reqs = []
     for i in range(8):
         plen = int(rng.integers(3, 17))
@@ -96,8 +92,8 @@ def test_paged_admission_backpressure(rng, mt_engine):
     overdrawing the pool, and everything still drains."""
     cfg, eng = mt_engine
     sched = ContinuousScheduler(eng, SchedulerConfig(
-        num_slots=6, bucket_min=8, kv_layout="paged", block_size=8,
-        num_blocks=8))      # 7 usable pages << 6 slots x 3 pages
+        num_slots=6, block_size=8,
+        num_blocks=8, prefill_chunk=16))  # 7 usable pages << 6 slots x 3
     for i in range(6):
         sched.submit(Request(
             rid=i, prompt=rng.integers(0, cfg.vocab_size, 16).astype(np.int32),
@@ -124,7 +120,7 @@ def test_page_starved_pool_decodes_without_thrash(rng, mt_engine):
     # 36-token prompt wants 5 pages: admitting B without reserve leaves
     # free = 1 and A's very next page-crossing starts the abort cycle.
     sched = ContinuousScheduler(eng, SchedulerConfig(
-        num_slots=4, bucket_min=8, kv_layout="paged", block_size=8,
+        num_slots=4, block_size=8,
         num_blocks=8, prefill_chunk=4))
     a = Request(rid=0, prompt=rng.integers(0, cfg.vocab_size, 8)
                 .astype(np.int32), task_id=0, max_new_tokens=6)
@@ -164,7 +160,7 @@ def test_mid_prefill_abort_recovers_and_recomputes(rng, mt_engine):
     # guard (free 6 >= 5 + 1), then A's depth-16 crossing at ~tick 9 finds
     # the pool dry and aborts B one chunk short of done.
     sched = ContinuousScheduler(eng, SchedulerConfig(
-        num_slots=4, bucket_min=8, kv_layout="paged", block_size=8,
+        num_slots=4, block_size=8,
         num_blocks=8, prefill_chunk=4))
     a = Request(rid=0, prompt=rng.integers(0, cfg.vocab_size, 8)
                 .astype(np.int32), task_id=0, max_new_tokens=14)
@@ -193,7 +189,7 @@ def test_finish_exactly_on_final_chunk_frees_pages(rng, mt_engine):
     and pages must free in that same tick (no decode step ever runs)."""
     cfg, eng = mt_engine
     sched = ContinuousScheduler(eng, SchedulerConfig(
-        num_slots=3, bucket_min=8, kv_layout="paged", block_size=8,
+        num_slots=3, block_size=8,
         prefill_chunk=8))
     reqs = [Request(rid=i, prompt=rng.integers(0, cfg.vocab_size, 4 + 7 * i)
                     .astype(np.int32), task_id=i % 3, max_new_tokens=1)
@@ -227,14 +223,14 @@ def test_fork_then_preempt_lineage_no_leaks(rng, mt_engine):
                       sampling=SamplingParams(temperature=0.9, top_p=0.9,
                                               seed=13, n=3))
         sched = ContinuousScheduler(eng, SchedulerConfig(
-            num_slots=4, bucket_min=8, kv_layout="paged", block_size=8,
-            num_blocks=num_blocks))
+            num_slots=4, block_size=8,
+            num_blocks=num_blocks, prefill_chunk=8))
         sched.submit(req)
         sched.run()
         sched.pool.check_no_leaks()
         return req, sched
 
-    roomy, _ = serve(num_blocks=0)          # capacity parity: no pressure
+    roomy, _ = serve(num_blocks=0)          # max_len per slot: no pressure
     tight, sched = serve(num_blocks=7)      # 6 usable pages: forces churn
     assert sched.pool.forks > 0, "setup failed: parent never forked"
     assert sched.preemptions > 0, "setup failed: no child was preempted"
@@ -245,7 +241,8 @@ def test_fork_then_preempt_lineage_no_leaks(rng, mt_engine):
 
 def test_streaming_and_latency_bookkeeping(rng, mt_engine):
     cfg, eng = mt_engine
-    sched = ContinuousScheduler(eng, SchedulerConfig(num_slots=2, bucket_min=8))
+    sched = ContinuousScheduler(eng, SchedulerConfig(num_slots=2,
+                                                     prefill_chunk=8))
     seen = []
     req = Request(rid=0, prompt=rng.integers(0, cfg.vocab_size, 6).astype(np.int32),
                   task_id=1, max_new_tokens=4,
@@ -266,29 +263,29 @@ def test_request_too_long_rejected(rng, mt_engine):
         sched.submit(Request(rid=1, prompt=long_prompt[:4], max_new_tokens=0))
 
 
-def test_slot_pool_churn(rng, tiny_lm):
-    """Admit/finish churn never leaks or double-books slots."""
-    cfg, model, params = tiny_lm
-    pool = SlotKVPool(model, num_slots=4, max_len=16)
-    live = []
-    for i in range(300):
-        if live and (len(live) == 4 or rng.random() < 0.45):
-            pool.free(live.pop(int(rng.integers(0, len(live)))))
-        else:
-            slot = pool.alloc(task_id=int(rng.integers(0, 3)))
-            assert slot is not None and slot not in live
-            pool.cur_len[slot] = int(rng.integers(1, 16))
-            live.append(slot)
-        assert pool.num_free() == 4 - len(live)
-        if not pool.has_free():
-            assert pool.alloc() is None
-        pool.check_no_leaks()
-    for s in list(live):
-        pool.free(s)
-    pool.check_no_leaks()
-    assert pool.num_free() == 4
-    with pytest.raises(ValueError):
-        pool.free(0)
+def test_sliding_window_model_refused_and_served_statically(rng):
+    """The paged serve path has no window masking yet: building a
+    scheduler for a sliding-window model fails with that reason, and the
+    static engine still serves the model — its greedy tokens are the
+    windowed full forward's argmax, with the prompt longer than the
+    window so the ring-buffered cache wraps."""
+    import jax
+    from repro import configs
+    from repro.models.model import Model, ModelOptions
+    cfg = configs.reduced(configs.get("h2o-danube-1.8b"))
+    assert cfg.attn_kind == "swa" and cfg.sliding_window == 16
+    model = Model(cfg, ModelOptions(chunk_q=8, chunk_kv=8))
+    params = model.init(jax.random.PRNGKey(0))
+    eng = ServeEngine(model, params, ServeConfig(max_len=48))
+    with pytest.raises(AssertionError, match="no sliding-window masking"):
+        ContinuousScheduler(eng, SchedulerConfig(num_slots=2,
+                                                 prefill_chunk=8))
+    prompt = rng.integers(0, cfg.vocab_size, (2, 20)).astype(np.int32)
+    out = eng.generate(prompt, 10)
+    logits, _ = model.logits(params, {"tokens": jnp.asarray(
+        np.concatenate([prompt, out[:, :-1]], axis=1))})
+    np.testing.assert_array_equal(
+        np.asarray(jnp.argmax(logits[:, 19:], axis=-1)), out)
 
 
 def test_paged_pool_churn(rng, tiny_lm):
@@ -351,8 +348,9 @@ def test_scheduler_drains_under_churn(rng, mt_engine):
     """Many more requests than slots: every request finishes, slots all
     return to the free list, totals add up."""
     cfg, eng = mt_engine
-    sched = ContinuousScheduler(eng, SchedulerConfig(num_slots=2, bucket_min=8,
-                                                     admit_per_step=1))
+    sched = ContinuousScheduler(eng, SchedulerConfig(num_slots=2,
+                                                     prefill_chunk=8,
+                                                     max_prefills=1))
     n = 11
     for i in range(n):
         sched.submit(Request(
@@ -402,8 +400,9 @@ def test_mixed_step_pallas_decode_parity(rng, tiny_lm):
 
 def test_paged_mixed_step_decode_parity(rng, tiny_lm):
     """A paged cache built from a contiguous prefill (rows scattered into
-    scrambled pages) decodes identically to the contiguous mixed step —
-    through both the XLA gather path and the Pallas paged kernel."""
+    scrambled pages) decodes through the paged mixed step identically to
+    the contiguous decode step — through both the XLA gather path and the
+    Pallas ragged kernel."""
     from repro.models.model import Model, ModelOptions
     cfg, model, params = tiny_lm
     pmodel = Model(cfg, ModelOptions(chunk_q=8, chunk_kv=8, attn_impl="pallas"))
@@ -432,12 +431,13 @@ def test_paged_mixed_step_decode_parity(rng, tiny_lm):
     step_tok = jnp.asarray(rng.integers(0, cfg.vocab_size, (b, 1)), jnp.int32)
     pos = jnp.asarray(depths)
     btj = jnp.asarray(bt)
+    rows = jnp.arange(b, dtype=jnp.int32)
     lg_ref, _ = model.decode_step(params, step_tok, pos, cache)
-    lg_paged, _ = model.decode_step(params, step_tok, pos, paged,
-                                    block_tables=btj)
-    lg_pal, _ = pmodel.decode_step(params, step_tok, pos, paged,
+    lg_paged, _ = model.mixed_step(params, step_tok, rows, pos, paged,
                                    block_tables=btj)
-    np.testing.assert_allclose(np.asarray(lg_ref), np.asarray(lg_paged),
+    lg_pal, _ = pmodel.mixed_step(params, step_tok, rows, pos, paged,
+                                  block_tables=btj)
+    np.testing.assert_allclose(np.asarray(lg_ref[:, 0]), np.asarray(lg_paged),
                                atol=2e-5, rtol=2e-5)
-    np.testing.assert_allclose(np.asarray(lg_ref), np.asarray(lg_pal),
+    np.testing.assert_allclose(np.asarray(lg_ref[:, 0]), np.asarray(lg_pal),
                                atol=2e-5, rtol=2e-5)
